@@ -3,10 +3,11 @@
 PRs 1-5 minimized host traffic, so the per-iteration latency left sits
 inside the feature kernels themselves: the single-buffered combine
 kernel serializes four aligned block DMAs before each 128-row tile's
-one-hot MXU expansion, and the scatter-update kernel issues one row DMA
-per admitted node.  The multi-buffered variants (paper §IV's prefetch
-argument applied at the VMEM level) hold ``depth`` tile windows in
-scratch and issue tile i+1's slab copy while tile i computes.
+one-hot MXU expansion, and the scatter-update kernel rewrites one
+sublane-aligned row block per grid step.  The multi-buffered variants
+(paper §IV's prefetch argument applied at the VMEM level) hold ``depth``
+tile windows in scratch and issue tile i+1's slab copy while tile i
+computes.
 
 This bench sweeps pipeline depth × tile size × feature width for both
 kernels, gates every depth>1 result bit-identical to the depth=1 kernel
@@ -43,8 +44,8 @@ import numpy as np
 
 from repro.kernels.gather_scatter_mm import (
     VMEM_SCRATCH_BUDGET_BYTES, cache_combine_pipelined_kernel_call,
-    cache_combine_tiled_kernel_call, cache_update_kernel_call,
-    cache_update_pipelined_kernel_call)
+    cache_combine_tiled_kernel_call, sublane_rows)
+from repro.kernels.ops import update_cache_rows
 
 from .common import calibrate_container, emit
 
@@ -125,25 +126,13 @@ def bench_update(k: int, f: int, m: int, t_f: int, depth: int, dtype,
         slots_np = rng.integers(0, k, m).astype(np.int32)
     else:
         slots_np = rng.permutation(k)[:m].astype(np.int32)
-    if depth > 1:
-        # the pipelined kernel's write DMAs are concurrent: destinations
-        # must be unique, so compact aliased slots keep-last on the host
-        # (exactly what ops.update_cache_rows does) — parity then holds
-        # against the sequential kernel bit-for-bit
-        _, first_in_rev = np.unique(slots_np[::-1], return_index=True)
-        keep = np.sort(slots_np.shape[0] - 1 - first_in_rev)
-        rows_k, slots_k = rows[keep], jnp.asarray(slots_np[keep])
-        b = 8
-        mp = -(-rows_k.shape[0] // b) * b
-        rows_k = jnp.pad(rows_k, ((0, mp - rows_k.shape[0]), (0, 0)))
-        call = jax.jit(lambda: cache_update_pipelined_kernel_call(
-            cache, rows_k, slots_k, t_f=t_f, depth=depth, row_block=b,
-            interpret=True))
-        scratch = depth * b * t_f * cache.dtype.itemsize
-    else:
-        call = jax.jit(lambda: cache_update_kernel_call(
-            cache, rows, jnp.asarray(slots_np), t_f=t_f, interpret=True))
-        scratch = t_f * cache.dtype.itemsize
+    # through the refresh path's own wrapper: it compacts aliased slots
+    # keep-last and groups them by sublane-aligned row block, the layout
+    # both update kernels take
+    call = jax.jit(lambda: update_cache_rows(
+        cache, rows, slots_np, use_pallas=True, pipeline_depth=depth))
+    scratch = ((depth if depth > 1 else 1) * sublane_rows(dtype) * t_f
+               * cache.dtype.itemsize)
     out = np.asarray(call().astype(jnp.float32))
     oracle = np.array(cache.astype(jnp.float32))    # writable copy
     for i in range(m):                      # sequential last-writer-wins

@@ -13,6 +13,7 @@ import tempfile
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.core import HybridConfig, HybridGNNTrainer
 from repro.graph import GNNConfig, make_dataset
 
@@ -96,9 +97,10 @@ def main():
     ap.add_argument("--cache-assemble", default="auto",
                     choices=["auto", "jnp", "pallas"],
                     help="device-side cache+miss combine path: 'auto' "
-                         "picks pallas on TPU and jnp elsewhere; force "
-                         "'pallas' to exercise the (interpret-mode) "
-                         "kernels off-TPU, e.g. with a pipeline depth")
+                         "picks pallas when the accelerators are TPUs and "
+                         "jnp on host stand-ins; force 'pallas' to "
+                         "exercise the (interpret-mode) kernels off-TPU, "
+                         "e.g. with a pipeline depth")
     ap.add_argument("--kernel-pipeline-depth", type=int, default=1,
                     help="Pallas combine/scatter DMA pipeline depth: 1 = "
                          "single-buffered, 2-4 = multi-buffered "
@@ -148,6 +150,7 @@ def main():
                          "and queue depths instead of hanging (0 = off)")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     fanouts = tuple(int(x) for x in args.fanouts.split(","))
     ds = make_dataset(args.dataset, scale=args.scale, seed=0,

@@ -39,9 +39,9 @@ Ablation knobs reproduce Fig. 11 exactly:
     decayed hotness counters and, on the measured-vs-priced drift signal,
     the coldest cache slots are swapped for strictly-hotter observed
     uncached nodes (DistDGL-style admission).  The device block is
-    scatter-updated in place (cache_update kernel: one aligned row-block
-    DMA per admitted node) and every in-flight TFP payload combines
-    against the cache *version* its lookup was classified at, so a
+    scatter-updated in place (cache_update kernel: only the aligned row
+    blocks holding admitted rows move) and every in-flight TFP payload
+    combines against the cache *version* its lookup was classified at, so a
     refresh can never corrupt batches already past the load stage —
     losses are bit-identical with refresh on or off.
 
@@ -79,9 +79,12 @@ mapping is re-run with the measured rate (and measured alpha) and the
 refreshed shares handed to the runtime — the DRM keeps fine-tuning from
 there.
 
-On this container all logical devices are CPU cores; the protocol, queues and
-measurements are identical to a real multi-accelerator host — device kind
-only changes the programming layer underneath (paper Section III-C).
+Devices (``resolve_trainer_devices``): on a host with TPUs the CPU
+trainer runs on the host CPU device and accelerator trainer i on the i-th
+TPU.  On the CPU-only backend (``JAX_PLATFORMS=cpu``, the test setting)
+host devices stand in for the accelerators; the protocol, queues and
+measurements are the same — device kind only changes the programming
+layer underneath (paper Section III-C).
 
 Failure model & degraded modes
 ------------------------------
@@ -129,13 +132,38 @@ from repro.optim.optimizers import apply_updates
 
 from .drm import Assignment, KnobAutoTuner, StageTimes
 from .perfmodel import (PLATFORMS, CalibratedKnobModel, KnobBounds,
-                        KnobState, SignalSnapshot, initial_task_mapping)
+                        KnobState, SignalSnapshot, initial_task_mapping,
+                        platform_for_device_kind)
 from .pipeline import PipelineItem, PrefetchPipeline, Stage
-from .protocol import Runtime, Synchronizer, TrainerHandle
+from .protocol import Runtime, Synchronizer, TrainerHandle, device_of
 
-__all__ = ["HybridConfig", "HybridGNNTrainer", "IterationMetrics"]
+__all__ = ["HybridConfig", "HybridGNNTrainer", "IterationMetrics",
+           "resolve_trainer_devices"]
 
 PyTree = Any
+
+
+def resolve_trainer_devices(n_accel: int, cpu_devices: Sequence[Any],
+                            accel_devices: Sequence[Any]
+                            ) -> Tuple[Any, List[Any]]:
+    """(CPU trainer's device, [device of accelerator trainer i]).
+
+    With accelerators present the CPU trainer takes the host CPU device
+    and trainer i the i-th accelerator; asking for more trainers than
+    there are accelerators is an error, never a silent fold of two
+    trainers onto one chip.  Without accelerators (the CPU-only backend)
+    the host devices stand in: the CPU trainer takes device 0 and trainer
+    i device (i + 1) modulo their count, so tests on forced host devices
+    keep the CPU trainer and accel0 apart.
+    """
+    if accel_devices:
+        if n_accel > len(accel_devices):
+            raise ValueError(
+                f"n_accel={n_accel} accelerator trainers but only "
+                f"{len(accel_devices)} accelerator devices on this host")
+        return cpu_devices[0], list(accel_devices[:n_accel])
+    return cpu_devices[0], [cpu_devices[(i + 1) % len(cpu_devices)]
+                            for i in range(n_accel)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -264,6 +292,9 @@ class IterationMetrics:
     t_sync: float
     edges: int
     assignment: Tuple[int, int]       # (cpu_batch, accel_batch_each)
+    grad_devices: Dict[str, str] = dataclasses.field(default_factory=dict)
+                                      # trainer -> "platform:id" of the
+                                      #   device its gradients came from
     cache_hit_rate: float = 0.0       # measured (epoch-window) cache hit rate
     cache_version: int = 0            # cache version after this iteration
                                       #   (> 0 once a dynamic refresh fired)
@@ -280,6 +311,15 @@ class IterationMetrics:
 
 class _TrainerFailure(RuntimeError):
     pass
+
+
+def _grad_fn(gnn_cfg: GNNConfig) -> Callable:
+    def _grad(params, batch: MiniBatch, x0):
+        (loss, acc), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, gnn_cfg, batch, x0)
+        return grads, {"loss": loss, "acc": acc}
+
+    return jax.jit(_grad)
 
 
 # Deliberately UNGUARDED shared state: _fail_at (written once before the
@@ -313,10 +353,16 @@ class HybridGNNTrainer:
         self._refresh_failures = 0        # consecutive stage() failures
         self._refresh_disabled = False    # budget spent: refresh is off
 
-        devices = jax.devices()
-        self.cpu_device = devices[0]
-        self.accel_devices = [devices[i % len(devices)]
-                              for i in range(1, 1 + cfg.n_accel)]
+        self.cpu_device, self.accel_devices = resolve_trainer_devices(
+            cfg.n_accel, jax.devices("cpu"),
+            [d for d in jax.devices() if d.platform != "cpu"])
+        # the perf model prices the accelerator that is there: a TPU's
+        # peaks come from its device kind, the configured platform only
+        # stands in for host devices
+        self.accel_platform = cfg.accel_platform
+        if self.accel_devices and self.accel_devices[0].platform != "cpu":
+            self.accel_platform = platform_for_device_kind(
+                self.accel_devices[0].device_kind)
 
         # --- parameters / optimizer (single authoritative copy) -------------
         key = jax.random.PRNGKey(cfg.seed)
@@ -390,7 +436,8 @@ class HybridGNNTrainer:
         self._staged_feedback: Optional[Tuple[float, float]] = None
         self._assemble_pallas = (cfg.cache_assemble == "pallas"
                                  or (cfg.cache_assemble == "auto"
-                                     and jax.default_backend() == "tpu"))
+                                     and any(d.platform == "tpu" for d in
+                                             self.accel_devices)))
         if self.cache is not None:
             if fault_injector is not None:
                 self.cache.fault_injector = fault_injector
@@ -422,7 +469,7 @@ class HybridGNNTrainer:
 
         # --- initial task mapping from the performance model (design time) ---
         host = PLATFORMS[cfg.host_platform]
-        accel = PLATFORMS[cfg.accel_platform]
+        accel = PLATFORMS[self.accel_platform]
         hit_rate = self.cache.expected_hit_rate if self.cache else 0.0
         self._model_hit_rate = hit_rate   # rate the current mapping is priced on
         if cfg.hybrid and cfg.n_accel == 0:
@@ -498,13 +545,14 @@ class HybridGNNTrainer:
                 min_gain=cfg.autotune_min_gain,
                 warmup_windows=cfg.autotune_warmup_windows)
 
-        # --- jit'd gradient function (shared across trainers/devices) --------
-        def _grad(params, batch: MiniBatch, x0):
-            (loss, acc), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, gnn_cfg, batch, x0)
-            return grads, {"loss": loss, "acc": acc}
-
-        self._grad_jit = jax.jit(_grad)
+        # --- jit'd gradient functions (one per aggregation path) -------------
+        # each call runs on the device its committed inputs live on; the
+        # CPU trainer aggregates with jnp, since Pallas kernels run only
+        # on TPU arrays
+        self._grad_jit = _grad_fn(gnn_cfg)
+        self._grad_jit_cpu = (
+            _grad_fn(dataclasses.replace(gnn_cfg, agg_impl="dense"))
+            if gnn_cfg.agg_impl.startswith("pallas") else self._grad_jit)
         self.history: List[IterationMetrics] = []
         self._ckpt_cb: Optional[Callable[[int, PyTree, PyTree], None]] = None
 
@@ -801,8 +849,7 @@ class HybridGNNTrainer:
         is active, which used to shift every accelerator onto its
         neighbour's device.
         """
-        ordinal = int(name[len("accel"):])
-        return self.accel_devices[ordinal % max(len(self.accel_devices), 1)]
+        return self.accel_devices[int(name[len("accel"):])]
 
     def _stage_transfer(self, item: PipelineItem) -> PipelineItem:
         p = item.payload
@@ -850,9 +897,13 @@ class HybridGNNTrainer:
         if not active:        # every trainer of this batch has died
             zero = jax.tree.map(jnp.zeros_like, self.params)
             return (zero, {"t_tc": 0.0, "t_ta": 0.0},
-                    {"loss": float("nan"), "acc": float("nan")})
-        sync = Synchronizer(len(active))
+                    {"loss": float("nan"), "acc": float("nan"),
+                     "grad_devices": {}})
+        # gradients are summed where the authoritative params live
+        sync = Synchronizer(len(active),
+                            device=device_of(self.params))
         results: Dict[str, Dict[str, Any]] = {}
+        errors: List[Exception] = []
 
         def work(idx: int, name: str, kind: str):
             if self._fail_at.get(name) == p["iteration"]:
@@ -863,20 +914,38 @@ class HybridGNNTrainer:
                 results[name] = {"loss": jnp.nan, "acc": jnp.nan,
                                  "t_train": 0.0, "failed": True}
                 return
-            handle = TrainerHandle(name=name, kind=kind, device=None,
-                                   grad_fn=self._grad_jit, index=idx)
+            handle = TrainerHandle(
+                name=name, kind=kind,
+                device=(self.cpu_device if kind == "cpu"
+                        else self._accel_device(name)),
+                grad_fn=(self._grad_jit_cpu if kind == "cpu"
+                         else self._grad_jit), index=idx)
             weight = float(p["shares"][name])
-            metrics = handle.run(sync, self.params, weight,
-                                 p["minibatch"][name], p["features"][name])
+            try:
+                metrics = handle.run(sync, self.params, weight,
+                                     p["minibatch"][name],
+                                     p["features"][name])
+            except Exception as e:
+                # a trainer whose step raises must not leave the
+                # synchronizer waiting for it: hand in nothing, and the
+                # error is raised once every trainer has returned
+                errors.append(e)
+                sync.submit(idx, jax.tree.map(jnp.zeros_like, self.params),
+                            0.0)
+                return
             results[name] = metrics
 
         threads = [threading.Thread(target=work, args=(i, n, k))
                    for i, (n, k) in enumerate(active)]
         for t in threads:
             t.start()
-        avg = sync.all_reduce()
-        for t in threads:
-            t.join()
+        try:
+            avg = sync.all_reduce()
+        finally:
+            for t in threads:
+                t.join()
+            if errors:
+                raise errors[0]
 
         # stage-time bookkeeping for the DRM engine
         t_tc = max((m["t_train"] for n, m in results.items()
@@ -888,7 +957,9 @@ class HybridGNNTrainer:
         wsum = max(sum(w.values()), 1e-9)
         loss = float(sum(float(m["loss"]) * w[n] for n, m in ok.items()) / wsum)
         acc = float(sum(float(m["acc"]) * w[n] for n, m in ok.items()) / wsum)
-        return avg, {"t_tc": t_tc, "t_ta": t_ta}, {"loss": loss, "acc": acc}
+        return avg, {"t_tc": t_tc, "t_ta": t_ta}, {
+            "loss": loss, "acc": acc,
+            "grad_devices": {n: m["device"] for n, m in ok.items()}}
 
     def _window_alpha(self, stats) -> float:
         """Eq. 7/8 alpha from measured window stats: unique-miss /
@@ -946,7 +1017,7 @@ class HybridGNNTrainer:
         local, peer, uf = self._sharded_pricing(measured)
         mapping = initial_task_mapping(
             PLATFORMS[self.cfg.host_platform],
-            PLATFORMS[self.cfg.accel_platform],
+            PLATFORMS[self.accel_platform],
             self.cfg.n_accel, self.cfg.total_batch,
             self.gnn_cfg.fanouts, self.gnn_cfg.layer_dims,
             model=self.gnn_cfg.model, cache_hit_rate=local,
@@ -1183,7 +1254,7 @@ class HybridGNNTrainer:
                      else self.dataset.feat_dim * 4)
         return CalibratedKnobModel(
             host=PLATFORMS[self.cfg.host_platform],
-            accel=PLATFORMS[self.cfg.accel_platform],
+            accel=PLATFORMS[self.accel_platform],
             ref=self._knobs,
             signals=SignalSnapshot(
                 t_sc=mean_times.t_sc, t_sa=mean_times.t_sa,
@@ -1327,6 +1398,7 @@ class HybridGNNTrainer:
                 iteration=p["iteration"], loss=metrics["loss"],
                 acc=metrics["acc"], times=times, t_sync=t_sync, edges=edges,
                 assignment=self.runtime.quantized_shares(),
+                grad_devices=metrics["grad_devices"],
                 cache_hit_rate=(self.cache.measured_hit_rate()
                                 if self.cache else 0.0),
                 cache_version=self.cache.version if self.cache else 0)

@@ -16,7 +16,8 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["PlatformSpec", "PLATFORMS", "WorkloadSpec", "StagePrediction",
+__all__ = ["PlatformSpec", "PLATFORMS", "DEVICE_KINDS",
+           "platform_for_device_kind", "WorkloadSpec", "StagePrediction",
            "predict", "initial_task_mapping", "mteps",
            "calibrate_sampling", "predict_epoch_time",
            "KnobState", "KnobBounds", "SignalSnapshot",
@@ -54,10 +55,31 @@ PLATFORMS: Dict[str, PlatformSpec] = {
                                13900, 2.0, False),
     "alveo-u250": PlatformSpec("alveo-u250", 0.6, 77.0, 16.0, 54.0,
                                2048, 0.3, True),
-    # target hardware for the dry-run/roofline (TPU v5e per prompt constants)
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip,
+    # 16 GB of HBM at 819 GB/s, 1,600 Gbit/s (200 GB/s) of inter-chip
+    # interconnect.  Not on that page, and assumed here: the 16 GB/s
+    # host link (PCIe gen4 x16), the on-chip MB, and 4 MXUs of 128x128
+    # MACs at 0.94 GHz
     "tpu-v5e":    PlatformSpec("tpu-v5e", 197.0, 819.0, 16.0, 128.0,
                                4 * 128 * 128, 0.94, True, ici_gbps=200.0),
 }
+
+# jax Device.device_kind -> PLATFORMS key of the accelerator it is
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+}
+
+
+def platform_for_device_kind(kind: str) -> str:
+    """The ``PLATFORMS`` key whose peaks price a device of ``kind``.  A
+    kind without a row is an error: the task mapping must not price an
+    unknown chip with another chip's peaks."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KINDS)}") from None
 
 
 @dataclasses.dataclass(frozen=True)

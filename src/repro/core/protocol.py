@@ -8,7 +8,8 @@ Defines how processors and accelerators interact and synchronize:
   (weighted by mini-batch share — sync SGD over unequal shares), and the
   averaged gradients are broadcast back.
 * ``TrainerHandle`` — one logical GNN Trainer bound to a device and a jit'd
-  gradient function; ``kind`` distinguishes the CPU trainer from
+  gradient function; it computes on its own copy of the parameters, on
+  its own device; ``kind`` distinguishes the CPU trainer from
   accelerator trainers (the protocol's application layer is accelerator
   agnostic — GPU/FPGA/TPU only changes the programming layer underneath,
   which for us is always XLA).
@@ -31,7 +32,7 @@ from repro.analysis.annotations import guarded_by
 
 from .drm import Assignment, DRMEngine, StageTimes
 
-__all__ = ["Synchronizer", "TrainerHandle", "Runtime"]
+__all__ = ["Synchronizer", "TrainerHandle", "Runtime", "device_of"]
 
 PyTree = Any
 
@@ -40,8 +41,9 @@ PyTree = Any
 class Synchronizer:
     """Listing-1 handshake: pthread cond/mutex -> threading.Condition."""
 
-    def __init__(self, n_trainers: int) -> None:
+    def __init__(self, n_trainers: int, device: Any) -> None:
         self.n_trainers = n_trainers
+        self.device = device          # where the average is formed
         self._cond = threading.Condition()
         self._done = 0
         self._slots: List[Optional[Tuple[PyTree, float]]] = [None] * n_trainers
@@ -58,7 +60,9 @@ class Synchronizer:
 
         Weighted by mini-batch share so that hybrid training with unequal
         shares is algorithmically identical to single-device large-batch
-        SGD (paper Section II-B).
+        SGD (paper Section II-B).  Every trainer's gradients are first
+        brought to ``device`` (the authoritative parameters' device):
+        trainers on different devices cannot be summed in place.
         """
         with self._cond:
             while self._done != self.n_trainers:       # Listing 1 line 24
@@ -67,6 +71,7 @@ class Synchronizer:
             self._done = 0
             self._slots = [None] * self.n_trainers
         total_w = sum(w for _, w in slots)
+        slots = [(jax.device_put(g, self.device), w) for g, w in slots]
         scaled = [jax.tree.map(lambda g: g * (w / total_w), g)
                   for g, w in slots]
         avg = scaled[0]
@@ -87,13 +92,21 @@ class TrainerHandle:
     def run(self, sync: Synchronizer, params: PyTree, weight: float,
             *args: Any) -> Dict[str, Any]:
         t0 = time.perf_counter()
+        params = jax.device_put(params, self.device)
         grads, metrics = self.grad_fn(params, *args)
         grads = jax.block_until_ready(grads)
         dt = time.perf_counter() - t0
         sync.submit(self.index, grads, weight)          # DONE++, signal
         metrics = dict(metrics)
         metrics["t_train"] = dt
+        dev = device_of(grads)
+        metrics["device"] = f"{dev.platform}:{dev.id}"
         return metrics
+
+
+def device_of(tree: PyTree) -> Any:
+    """The device holding ``tree``'s first leaf."""
+    return next(iter(jax.tree.leaves(tree)[0].devices()))
 
 
 class Runtime:

@@ -204,15 +204,6 @@ def params_shardings(tree: Any, mesh: Mesh) -> Any:
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking disabled
-    (jax<=0.4 spells the kwarg ``check_rep``, newer jax ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

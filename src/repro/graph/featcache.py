@@ -23,8 +23,8 @@ accumulates per-slot hit counters and a decayed hotness estimate for the
 uncached ids it missed on, and
 ``refresh()`` evicts the coldest slots in favor of strictly-hotter
 uncached nodes — updating the device-resident block in place with the
-``cache_update`` scatter kernel (one aligned row-block DMA per admitted
-node) instead of re-uploading all K rows.
+``cache_update`` scatter kernel (one sublane-aligned row block rewritten
+per block that holds admitted rows) instead of re-uploading all K rows.
 
 Refreshing while the TFP pipeline has batches in flight needs a
 consistency protocol: a lookup classified against the slot table at
@@ -760,8 +760,8 @@ class FeatureCache:
         absent plan returns 0 and changes nothing.
 
         When rows move: ``version`` is bumped, each device-resident
-        current-version block is scatter-updated in place (one aligned
-        row-block DMA per admitted node via ``kernels.ops
+        current-version block is scatter-updated in place (the aligned
+        row blocks holding admitted rows, via ``kernels.ops
         .update_cache_rows``; snapshots older than ``keep_versions`` are
         retired), and the epoch stats window resets so measured-rate
         consumers see the post-refresh rate.  Returns the number of rows

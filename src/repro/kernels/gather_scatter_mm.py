@@ -37,7 +37,8 @@ __all__ = ["segment_sum_kernel_call", "fused_update_kernel_call",
            "cache_combine_kernel_call", "cache_combine_tiled_kernel_call",
            "cache_combine_pipelined_kernel_call",
            "cache_update_kernel_call", "cache_update_pipelined_kernel_call",
-           "VMEM_SCRATCH_BUDGET_BYTES", "check_vmem_scratch"]
+           "VMEM_SCRATCH_BUDGET_BYTES", "check_vmem_scratch",
+           "sublane_rows"]
 
 
 # Multi-buffered kernels hold ``depth`` in-flight tile windows in VMEM
@@ -215,77 +216,94 @@ def cache_combine_kernel_call(cache: jax.Array, miss: jax.Array,
 # ----------------------------------- cache scatter update (refresh path)
 
 
-def _cache_update_kernel(slots_ref, rows_ref, cache_ref, o_ref):
-    # grid = (M, F tiles): step (i, j) overwrites the F-tile j of cache row
-    # slots[i] with the matching tile of update row i.  The cache operand
-    # is aliased to the output, so rows no update points at keep their
-    # bytes without ever being re-DMA'd — the whole refresh moves exactly
-    # M * F elements.  Grid steps run sequentially, so an update set that
-    # aliases the same slot resolves to the last writer (the jnp reference
-    # in ref.cache_update applies updates in the same order).
-    o_ref[...] = rows_ref[...]
+def sublane_rows(dtype) -> int:
+    """Rows of one native (sublane, lane) tile of ``dtype``: 8 for 32-bit
+    types, 16 for 16-bit ones (two rows pack into a sublane).  DMAs and
+    blocks along the row axis must start and end on this boundary."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def cache_update_kernel_call(cache: jax.Array, rows: jax.Array,
-                             slots: jax.Array, t_f: int = 128,
+def _cache_update_kernel(blocks_ref, upd_ref, mask_ref, cache_ref, o_ref):
+    # grid = (T, F tiles): step (i, j) rewrites the F-tile j of the
+    # aligned R-row cache block blocks[i].  Rows the mask marks take the
+    # staged update row, the rest keep the cache's bytes.  The cache is
+    # aliased to the output and every block appears once, so the
+    # BlockSpec pipeline never reads a block it has yet to write back.
+    o_ref[...] = jnp.where(mask_ref[...] != 0, upd_ref[...], cache_ref[...])
+
+
+def cache_update_kernel_call(cache: jax.Array, upd: jax.Array,
+                             mask: jax.Array, blocks: jax.Array,
+                             t_f: int = 128,
                              interpret: bool = True) -> jax.Array:
-    """In-place scatter of admitted rows into the device-resident hot block:
-    ``out = cache; out[slots[i]] = rows[i]``.
+    """In-place scatter of admitted rows into the device-resident hot
+    block, one sublane-aligned R-row block per grid step
+    (R = ``sublane_rows(dtype)``).
 
     The dynamic cache refresh admits a handful of rows per epoch; this
-    kernel updates the [K, F] device block with one aligned (1, T_F)
-    row-block DMA per admitted node instead of re-uploading all K rows
-    over PCIe.  ``slots`` arrives via scalar prefetch so each grid step's
-    output BlockSpec index map steers the write to a data-dependent row —
-    the scatter dual of the combine kernels' gather above.
+    kernel updates the [K, F] device block by rewriting only the aligned
+    row blocks that hold admitted rows, instead of re-uploading all K rows
+    over PCIe.  A single-row block is not a legal TPU tile, so the host
+    (ops.update_cache_rows) groups the keep-last-deduped slots by block:
+    block ``blocks[t]`` takes ``upd[t*R + r]`` wherever
+    ``mask[t*R + r] != 0``.  ``blocks`` arrives via scalar prefetch so
+    the BlockSpec index maps steer the read and the aliased write-back to
+    data-dependent blocks — the scatter dual of the combine kernels'
+    gather below.
 
-    cache: [K, F] (F % t_f == 0, callers pad); rows: [M, F] (M >= 1 —
-    callers shortcut empty updates); slots: int32 [M] -> out [K, F].
-    Duplicate slots resolve to the last writer (grid order).
+    cache: [K, Fp] (K % R == 0, Fp % t_f == 0; callers pad);
+    upd: [T*R, Fp]; mask: int32 [T*R, 1]; blocks: int32 [T], unique
+    -> out [K, Fp].
     """
-    m = slots.shape[0]
+    rb = sublane_rows(cache.dtype)
+    t = blocks.shape[0]
     f = cache.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(m, f // t_f),
+        grid=(t, f // t_f),
         in_specs=[
-            pl.BlockSpec((1, t_f), lambda i, j, s: (i, j)),
-            pl.BlockSpec((1, t_f), lambda i, j, s: (s[i], j)),
+            pl.BlockSpec((rb, t_f), lambda i, j, b: (i, j)),
+            pl.BlockSpec((rb, 1), lambda i, j, b: (i, 0)),
+            pl.BlockSpec((rb, t_f), lambda i, j, b: (b[i], j)),
         ],
-        out_specs=pl.BlockSpec((1, t_f), lambda i, j, s: (s[i], j)),
+        out_specs=pl.BlockSpec((rb, t_f), lambda i, j, b: (b[i], j)),
     )
     return pl.pallas_call(
         _cache_update_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-        # operand order is (slots, rows, cache): alias the cache into the
-        # output so untouched rows are preserved, not recomputed
-        input_output_aliases={2: 0},
+        # operand order is (blocks, upd, mask, cache): alias the cache
+        # into the output so untouched blocks are never moved
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(slots, rows, cache)
+    )(blocks, upd, mask, cache)
 
 
 # ------------------------------------ tiled cache combine (multi-row DMA)
 
 
+def _expand_window(loc: jax.Array, win: jax.Array) -> jax.Array:
+    # loc: [T_N, 1] int32 row offsets into win: [4W, T_F] -> [T_N, T_F].
+    # The duplication of shipped rows back into the positional layout is a
+    # one-hot matmul, so it runs on the MXU instead of as a scalar gather.
+    # The offsets sit one per sublane, so the compare against the lane
+    # iota needs no relayout.
+    onehot = (loc == jax.lax.broadcasted_iota(
+        jnp.int32, (loc.shape[0], win.shape[0]), 1)).astype(jnp.float32)
+    return jax.lax.dot(onehot, win.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+
+
 def _cache_combine_tiled_kernel(base_ref, loc_ref,
-                                s0_ref, s1_ref, s2_ref, s3_ref, o_ref,
-                                *, window: int):
+                                s0_ref, s1_ref, s2_ref, s3_ref, o_ref):
     # One grid step materializes T_N output rows from a 4W-row VMEM window
     # (four consecutive aligned W-blocks of the dense source — enough to
     # cover any tile's monotone rank span, see
-    # cache_combine_tiled_kernel_call).  The expansion itself is a one-hot
-    # matmul so the duplication of shipped rows back into the positional
-    # layout runs on the MXU instead of as a scalar gather.
-    g = pl.program_id(0)
-    win = jnp.concatenate([s0_ref[...], s1_ref[...],
-                           s2_ref[...], s3_ref[...]], axis=0)   # [4W, T_F]
-    loc = loc_ref[g]                                            # [T_N] int32
-    onehot = (loc[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (loc.shape[0], 4 * window), 1)).astype(jnp.float32)
-    o_ref[...] = jax.lax.dot(
-        onehot, win.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST).astype(o_ref.dtype)
+    # cache_combine_tiled_kernel_call).
+    win = jnp.concatenate([s[...].astype(jnp.float32)
+                           for s in (s0_ref, s1_ref, s2_ref, s3_ref)],
+                          axis=0)                               # [4W, T_F]
+    o_ref[...] = _expand_window(loc_ref[...], win).astype(o_ref.dtype)
 
 
 def cache_combine_tiled_kernel_call(src: jax.Array, base: jax.Array,
@@ -304,8 +322,9 @@ def cache_combine_tiled_kernel_call(src: jax.Array, base: jax.Array,
     means its whole span (distinct rows + at most one bounded pad gap)
     fits inside four consecutive aligned W-row blocks (W = T_N).  Per tile
     the caller scalar-prefetches the aligned block index of the window
-    plus a T_N row table of offsets into it; the body expands the 4W-row
-    VMEM window through a one-hot MXU matmul.  Grid steps drop from N to
+    and blocks a T_N-row column of offsets into it into VMEM (a vector
+    table cannot be read back out of scalar memory); the body expands the
+    4W-row VMEM window through a one-hot MXU matmul.  Grid steps drop from N to
     N/T_N (~128x less grid overhead) and every DMA is a dense MXU-aligned
     (W, T_F) block instead of a single row.
 
@@ -319,22 +338,23 @@ def cache_combine_tiled_kernel_call(src: jax.Array, base: jax.Array,
     fp = src.shape[1]
     w = t_n
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(g, fp // t_f),
         in_specs=[
-            pl.BlockSpec((w, t_f), lambda i, j, b, loc: (b[i], j)),
-            pl.BlockSpec((w, t_f), lambda i, j, b, loc: (b[i] + 1, j)),
-            pl.BlockSpec((w, t_f), lambda i, j, b, loc: (b[i] + 2, j)),
-            pl.BlockSpec((w, t_f), lambda i, j, b, loc: (b[i] + 3, j)),
+            pl.BlockSpec((t_n, 1), lambda i, j, b: (i, 0)),
+            pl.BlockSpec((w, t_f), lambda i, j, b: (b[i], j)),
+            pl.BlockSpec((w, t_f), lambda i, j, b: (b[i] + 1, j)),
+            pl.BlockSpec((w, t_f), lambda i, j, b: (b[i] + 2, j)),
+            pl.BlockSpec((w, t_f), lambda i, j, b: (b[i] + 3, j)),
         ],
-        out_specs=pl.BlockSpec((t_n, t_f), lambda i, j, b, loc: (i, j)),
+        out_specs=pl.BlockSpec((t_n, t_f), lambda i, j, b: (i, j)),
     )
     return pl.pallas_call(
-        functools.partial(_cache_combine_tiled_kernel, window=w),
+        _cache_combine_tiled_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g * t_n, fp), src.dtype),
         interpret=interpret,
-    )(base, local, src, src, src, src)
+    )(base, local.reshape(g * t_n, 1), src, src, src, src)
 
 
 # ------------------- multi-buffered pipelined combine (DMA/compute overlap)
@@ -371,12 +391,8 @@ def _cache_combine_pipelined_kernel(base_ref, loc_ref, src_ref, o_ref,
 
     slot = jax.lax.rem(s, depth)
     window_dma(s, slot).wait()
-    loc = loc_ref[i]                                          # [T_N] int32
-    onehot = (loc[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (loc.shape[0], 4 * window), 1)).astype(jnp.float32)
-    o_ref[...] = jax.lax.dot(
-        onehot, win_ref[slot].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST).astype(o_ref.dtype)
+    o_ref[...] = _expand_window(loc_ref[...],
+                                win_ref[slot]).astype(o_ref.dtype)
 
     @pl.when(s + depth < nsteps)
     def _prefetch_next():   # the slot is free again: refill depth ahead
@@ -415,10 +431,11 @@ def cache_combine_pipelined_kernel_call(src: jax.Array, base: jax.Array,
         depth * 4 * w * t_f * src.dtype.itemsize,
         f"cache_combine_pipelined(depth={depth}, t_n={t_n}, t_f={t_f})")
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(g, nf),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((t_n, t_f), lambda i, j, b, loc: (i, j)),
+        in_specs=[pl.BlockSpec((t_n, 1), lambda i, j, b: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((t_n, t_f), lambda i, j, b: (i, j)),
         scratch_shapes=[pltpu.VMEM((depth, 4 * w, t_f), src.dtype),
                         pltpu.SemaphoreType.DMA((depth,))],
     )
@@ -428,35 +445,33 @@ def cache_combine_pipelined_kernel_call(src: jax.Array, base: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g * t_n, fp), src.dtype),
         interpret=interpret,
-    )(base, local, src)
+    )(base, local.reshape(g * t_n, 1), src)
 
 
 # ------------------ multi-buffered pipelined scatter update (refresh path)
 
 
-def _cache_update_pipelined_kernel(slots_ref, rows_ref, cache_ref, o_ref,
-                                   blk_ref, rd_sem, wr_sem, *, row_block: int,
-                                   t_f: int, depth: int, nf: int,
-                                   nsteps: int, m: int):
-    # The single-buffered scatter kernel moves one row per grid step:
-    # DMA in, DMA out, wait, repeat.  Here admitted rows are batched into
-    # ``row_block``-row block reads held in ``depth`` VMEM slots — block
-    # b+depth's read is in flight while block b's per-row write-back DMAs
-    # scatter into the aliased cache.  Callers guarantee ``slots`` are
-    # unique (ops.update_cache_rows dedupes keep-last on the host), so
-    # the write-backs of one block are mutually independent: start all,
-    # wait all, then the slot can be refilled.
-    bi = pl.program_id(0)
+def _cache_update_pipelined_kernel(blocks_ref, upd_ref, mask_ref, cache_ref,
+                                   o_ref, blk_ref, rd_sem, wr_sem, *,
+                                   row_block: int, t_f: int, depth: int,
+                                   nf: int, nsteps: int):
+    # Same merge as _cache_update_kernel, but the cache blocks move by
+    # hand: the cache stays in HBM (memory_space=ANY) and the read of the
+    # R-row block step s+depth rewrites is in flight in one of ``depth``
+    # VMEM slots while step s merges and writes its block back.  Every
+    # (block, F tile) pair appears once, so no read can overtake the
+    # write-back of the bytes it reads.
+    i = pl.program_id(0)
     j = pl.program_id(1)
-    s = bi * nf + j
+    s = i * nf + j
+
+    def cache_tile(step):
+        return (pl.ds(blocks_ref[step // nf] * row_block, row_block),
+                pl.ds(jax.lax.rem(step, nf) * t_f, t_f))
 
     def block_read(step, slot):
-        tb = step // nf
-        tj = jax.lax.rem(step, nf)
-        return pltpu.make_async_copy(
-            rows_ref.at[pl.ds(tb * row_block, row_block),
-                        pl.ds(tj * t_f, t_f)],
-            blk_ref.at[slot], rd_sem.at[slot])
+        return pltpu.make_async_copy(cache_ref.at[cache_tile(step)],
+                                     blk_ref.at[slot], rd_sem.at[slot])
 
     @pl.when(s == 0)
     def _warmup():
@@ -465,81 +480,58 @@ def _cache_update_pipelined_kernel(slots_ref, rows_ref, cache_ref, o_ref,
 
     slot = jax.lax.rem(s, depth)
     block_read(s, slot).wait()
-    for r in range(row_block):       # scatter the block's live rows
-
-        @pl.when(bi * row_block + r < m)
-        def _start_write():
-            pltpu.make_async_copy(
-                blk_ref.at[slot, pl.ds(r, 1), :],
-                o_ref.at[pl.ds(slots_ref[bi * row_block + r], 1),
-                         pl.ds(j * t_f, t_f)],
-                wr_sem.at[r]).start()
-
-    for r in range(row_block):       # block's writes drain before reuse
-
-        @pl.when(bi * row_block + r < m)
-        def _wait_write():
-            pltpu.make_async_copy(
-                blk_ref.at[slot, pl.ds(r, 1), :],
-                o_ref.at[pl.ds(slots_ref[bi * row_block + r], 1),
-                         pl.ds(j * t_f, t_f)],
-                wr_sem.at[r]).wait()
+    blk_ref[slot] = jnp.where(mask_ref[...] != 0, upd_ref[...],
+                              blk_ref[slot])
+    write = pltpu.make_async_copy(blk_ref.at[slot], o_ref.at[cache_tile(s)],
+                                  wr_sem.at[slot])
+    write.start()
+    write.wait()                      # the slot is free again
 
     @pl.when(s + depth < nsteps)
     def _prefetch_next():
         block_read(s + depth, slot).start()
 
 
-def cache_update_pipelined_kernel_call(cache: jax.Array, rows: jax.Array,
-                                       slots: jax.Array, t_f: int = 128,
-                                       depth: int = 2, row_block: int = 8,
+def cache_update_pipelined_kernel_call(cache: jax.Array, upd: jax.Array,
+                                       mask: jax.Array, blocks: jax.Array,
+                                       t_f: int = 128, depth: int = 2,
                                        interpret: bool = True) -> jax.Array:
     """Multi-buffered in-place scatter of admitted rows into the hot block.
 
-    Semantics match ``cache_update_kernel_call`` for *unique* slots
-    (``out = cache; out[slots[i]] = rows[i]``; callers pre-dedupe aliased
-    slots keep-last — ops.update_cache_rows does), but rows move as
-    ``row_block``-row block DMAs through ``depth`` VMEM slots: block
-    b+depth streams HBM->VMEM while block b's rows scatter VMEM->HBM into
-    the aliased cache, instead of one serialized row round-trip per grid
-    step.
+    Contract and output match ``cache_update_kernel_call`` (same operands,
+    same per-block merge), but the aligned R-row cache blocks move through
+    ``depth`` VMEM slots by hand: block t+depth streams HBM->VMEM while
+    block t merges and writes back into the aliased cache.
 
-    cache: [K, Fp] (Fp % t_f == 0); rows: [Mp, Fp] with Mp a row_block
-    multiple padded past M = slots.shape[0] (pad rows are never written);
-    slots: int32 [M], unique -> out [K, Fp].
+    cache: [K, Fp] (K % R == 0, Fp % t_f == 0); upd: [T*R, Fp];
+    mask: int32 [T*R, 1]; blocks: int32 [T], unique -> out [K, Fp].
     """
     if depth < 1:
         raise ValueError(f"pipeline depth must be >= 1, got {depth}")
-    m = slots.shape[0]
-    mp = rows.shape[0]
-    if mp % row_block != 0 or mp < m:
-        raise ValueError(
-            f"rows must be padded to the {row_block}-row block (got "
-            f"{mp} rows for {m} slots)")
+    rb = sublane_rows(cache.dtype)
+    t = blocks.shape[0]
     fp = cache.shape[1]
     nf = fp // t_f
-    nb = mp // row_block
     check_vmem_scratch(
-        depth * row_block * t_f * cache.dtype.itemsize,
-        f"cache_update_pipelined(depth={depth}, row_block={row_block}, "
-        f"t_f={t_f})")
+        depth * rb * t_f * cache.dtype.itemsize,
+        f"cache_update_pipelined(depth={depth}, row_block={rb}, t_f={t_f})")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nb, nf),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.VMEM((depth, row_block, t_f), cache.dtype),
+        grid=(t, nf),
+        in_specs=[pl.BlockSpec((rb, t_f), lambda i, j, b: (i, j)),
+                  pl.BlockSpec((rb, 1), lambda i, j, b: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((depth, rb, t_f), cache.dtype),
                         pltpu.SemaphoreType.DMA((depth,)),
-                        pltpu.SemaphoreType.DMA((row_block,))],
+                        pltpu.SemaphoreType.DMA((depth,))],
     )
     return pl.pallas_call(
-        functools.partial(_cache_update_pipelined_kernel,
-                          row_block=row_block, t_f=t_f, depth=depth,
-                          nf=nf, nsteps=nb * nf, m=m),
+        functools.partial(_cache_update_pipelined_kernel, row_block=rb,
+                          t_f=t_f, depth=depth, nf=nf, nsteps=t * nf),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-        # operand order is (slots, rows, cache): alias cache -> output
-        input_output_aliases={2: 0},
+        # operand order is (blocks, upd, mask, cache): alias cache -> output
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(slots, rows, cache)
+    )(blocks, upd, mask, cache)

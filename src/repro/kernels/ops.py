@@ -4,14 +4,16 @@ Handles padding to MXU-aligned tile multiples, 2-D reshaping of vector
 operands (TPU lanes want >=2-D), and dispatch between the Pallas path and
 the pure-jnp reference (``use_pallas=False`` or non-TPU-friendly shapes).
 
-On this CPU container kernels run in ``interpret=True`` mode (the kernel
-body executes in Python for correctness validation); on a real TPU the same
-``pallas_call`` compiles to Mosaic.
+Each kernel call is compiled to Mosaic where the enclosing computation is
+lowered for a TPU and runs in ``interpret=True`` mode (the kernel body
+executed op by op, for correctness validation) where it is lowered for
+the CPU of a host without a TPU.  The choice is made per lowering
+(``_on_device``), not once per process.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -19,20 +21,37 @@ import numpy as np
 
 from . import ref
 from .flash_attention import flash_attention_call
-from .gather_scatter_mm import (cache_combine_kernel_call,
-                                cache_combine_pipelined_kernel_call,
+from .gather_scatter_mm import (cache_combine_pipelined_kernel_call,
                                 cache_combine_tiled_kernel_call,
                                 cache_update_kernel_call,
                                 cache_update_pipelined_kernel_call,
                                 fused_update_kernel_call,
-                                segment_sum_kernel_call)
+                                segment_sum_kernel_call, sublane_rows)
 
 __all__ = ["segment_weighted_sum_regular", "fused_gnn_update",
            "flash_attention", "assemble_features",
            "assemble_features_sharded", "gather_rows",
            "update_cache_rows"]
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+@functools.lru_cache(maxsize=None)
+def _tpu_host() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _on_device(call, *args, **static):
+    """Run the Pallas kernel ``call`` on ``args``: compiled where the
+    enclosing computation is lowered for a TPU, interpreted where it is
+    lowered for another platform.  ``lax.platform_dependent`` keeps only
+    the branch of the platform being lowered, so a CPU trainer's jit and a
+    TPU trainer's jit in one process each get theirs.  On a host that has
+    a TPU the other branch is not interpreted either: Pallas lowers only
+    interpret mode for the CPU, so a kernel lowered there fails loudly
+    instead of quietly running a slow path."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(call, interpret=False, **static),
+        default=functools.partial(call, interpret=not _tpu_host(), **static))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -223,12 +242,11 @@ def _assemble_tiled_device(cache, miss, hit_table, miss_table, base,
     fp = _round_up(f, t_f)
     src = jnp.pad(src, ((0, sp - src.shape[0]), (0, fp - f)))
     if depth > 1:
-        out = cache_combine_pipelined_kernel_call(
-            src, base, local, t_n=w, t_f=t_f, depth=depth,
-            interpret=_INTERPRET)
+        out = _on_device(cache_combine_pipelined_kernel_call, src, base,
+                         local, t_n=w, t_f=t_f, depth=depth)
     else:
-        out = cache_combine_tiled_kernel_call(src, base, local, t_n=w,
-                                              t_f=t_f, interpret=_INTERPRET)
+        out = _on_device(cache_combine_tiled_kernel_call, src, base, local,
+                         t_n=w, t_f=t_f)
     return jnp.take(out, inv, axis=0)[:, :f]
 
 
@@ -241,34 +259,47 @@ def update_cache_rows(cache: jax.Array, rows, slots,
 
     ``rows``/``slots`` are accepted as host numpy (refresh builds them on
     the host); an empty update returns the input block unchanged so a
-    no-op refresh never touches the device.  The Pallas path issues one
-    aligned row-block DMA per admitted node with the cache aliased into
-    the output; the jnp path compacts aliased slots to their last writer
-    on the host so its XLA scatter (duplicate-index order unspecified)
-    stays deterministic.
+    no-op refresh never touches the device.  Aliased slots are first
+    compacted keep-last on the host, so the jnp path's XLA scatter
+    (duplicate-index order unspecified) stays deterministic and the Pallas
+    kernels see every slot once.  The Pallas path then groups the slots
+    by their sublane-aligned row block (a one-row DMA is not a legal TPU
+    tile) and rewrites each touched block once, with the cache aliased
+    into the output.
 
-    ``pipeline_depth > 1`` (Pallas path only) batches the admitted rows
-    into multi-row block reads held in ``depth`` VMEM slots, overlapped
-    with the per-row aliased write-back.  The pipelined kernel's write
-    DMAs within a block are concurrent, so aliased slots are compacted
-    keep-last on the host first (same dedupe the jnp path needs) — the
-    result stays bit-identical to the sequential kernel and the oracle.
+    ``pipeline_depth > 1`` (Pallas path only) moves the touched blocks
+    through ``depth`` VMEM slots by hand, overlapping block t+depth's read
+    with block t's write-back — bit-identical to depth 1 and the oracle.
     """
     slots = np.asarray(slots, dtype=np.int32)
     if slots.shape[0] == 0:
         return cache
     rows = jnp.asarray(rows, dtype=cache.dtype)
-    if not use_pallas or pipeline_depth > 1:
-        # keep-last dedupe: unique() keeps the first occurrence, so scan
-        # the reversed slot list and map indices back
-        _, first_in_rev = np.unique(slots[::-1], return_index=True)
-        keep = np.sort(slots.shape[0] - 1 - first_in_rev)
-        if not use_pallas:
-            return _update_ref(cache, rows[keep], jnp.asarray(slots[keep]))
-        return _update_pallas_pipelined(cache, rows[keep],
-                                        jnp.asarray(slots[keep]),
-                                        depth=int(pipeline_depth))
-    return _update_pallas(cache, rows, jnp.asarray(slots))
+    # keep-last dedupe: unique() keeps the first occurrence, so scan the
+    # reversed slot list and map indices back
+    _, first_in_rev = np.unique(slots[::-1], return_index=True)
+    keep = np.sort(slots.shape[0] - 1 - first_in_rev).astype(np.int32)
+    if not use_pallas:
+        return _update_ref(cache, rows[keep], jnp.asarray(slots[keep]))
+    src, mask, blocks = _update_schedule(slots[keep], keep,
+                                         sublane_rows(cache.dtype))
+    return _update_pallas(cache, rows, src, mask, blocks,
+                          depth=int(pipeline_depth))
+
+
+def _update_schedule(slots: np.ndarray, src_rows: np.ndarray, rb: int
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Group unique ``slots`` by their ``rb``-row block: the touched
+    blocks in ascending order, and for row r of the t-th block the update
+    row it takes (``src[t*rb + r]``, an index into the caller's rows) and
+    whether it takes one at all (``mask``)."""
+    blocks, inv = np.unique(slots // rb, return_inverse=True)
+    pos = inv * rb + slots % rb
+    src = np.zeros(blocks.shape[0] * rb, np.int32)
+    mask = np.zeros(blocks.shape[0] * rb, np.int32)
+    src[pos] = src_rows
+    mask[pos] = 1
+    return src, mask, blocks.astype(np.int32)
 
 
 @jax.jit
@@ -277,38 +308,23 @@ def _update_ref(cache: jax.Array, rows: jax.Array,
     return cache.at[slots].set(rows)
 
 
-@jax.jit
-def _update_pallas(cache: jax.Array, rows: jax.Array,
-                   slots: jax.Array) -> jax.Array:
-    f = cache.shape[1]
-    t_f = _pick_tile(f)
-    fp = _round_up(f, t_f)
-    cp = jnp.pad(cache, ((0, 0), (0, fp - f)))
-    rp = jnp.pad(rows, ((0, 0), (0, fp - f)))
-    out = cache_update_kernel_call(cp, rp, slots, t_f=t_f,
-                                   interpret=_INTERPRET)
-    return out[:, :f]
-
-
-_UPDATE_ROW_BLOCK = 8      # rows per block DMA in the pipelined scatter
-
-
 @functools.partial(jax.jit, static_argnames=("depth",))
-def _update_pallas_pipelined(cache: jax.Array, rows: jax.Array,
-                             slots: jax.Array, depth: int) -> jax.Array:
-    f = cache.shape[1]
+def _update_pallas(cache: jax.Array, rows: jax.Array, src: jax.Array,
+                   mask: jax.Array, blocks: jax.Array,
+                   depth: int) -> jax.Array:
+    k, f = cache.shape
     t_f = _pick_tile(f)
     fp = _round_up(f, t_f)
-    b = _UPDATE_ROW_BLOCK
-    mp = _round_up(rows.shape[0], b)
-    cp = jnp.pad(cache, ((0, 0), (0, fp - f)))
-    # pad rows up to the block multiple: pad rows stream through the block
-    # reads but are never written back (the kernel guards on the live count)
-    rp = jnp.pad(rows, ((0, mp - rows.shape[0]), (0, fp - f)))
-    out = cache_update_pipelined_kernel_call(cp, rp, slots, t_f=t_f,
-                                             depth=depth, row_block=b,
-                                             interpret=_INTERPRET)
-    return out[:, :f]
+    kp = _round_up(k, sublane_rows(cache.dtype))
+    cp = jnp.pad(cache, ((0, kp - k), (0, fp - f)))
+    upd = jnp.pad(jnp.take(rows, src, axis=0), ((0, 0), (0, fp - f)))
+    if depth > 1:
+        out = _on_device(cache_update_pipelined_kernel_call, cp, upd,
+                         mask[:, None], blocks, t_f=t_f, depth=depth)
+    else:
+        out = _on_device(cache_update_kernel_call, cp, upd, mask[:, None],
+                         blocks, t_f=t_f)
+    return out[:k, :f]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -335,8 +351,8 @@ def _segsum_fwd_impl(x_nbr: jax.Array, w_edge: jax.Array,
                  ((0, dp - d), (0, 0), (0, fp - f))).reshape(dp * fanout, fp)
     we = jnp.pad(w_edge.reshape(d, fanout), ((0, dp - d), (0, 0))
                  ).reshape(dp * fanout, 1)
-    out = segment_sum_kernel_call(xn, we, fanout, t_d=t_d, t_f=t_f,
-                                  interpret=_INTERPRET)
+    out = _on_device(segment_sum_kernel_call, xn, we, fanout=fanout,
+                     t_d=t_d, t_f=t_f)
     return out[:d, :f]
 
 
@@ -394,9 +410,8 @@ def _fused_fwd_impl(x_self: jax.Array, x_nbr: jax.Array, w_edge: jax.Array,
     wa = jnp.pad(w_agg, ((0, fp - f), (0, op - o)))
     b = (jnp.zeros((1, op), x_self.dtype) if bias is None
          else jnp.pad(bias.reshape(1, o), ((0, 0), (0, op - o))))
-    out = fused_update_kernel_call(xs, xn, we, ss, ws, wa, b, fanout,
-                                   t_d=t_d, t_f=t_f, t_o=t_o,
-                                   interpret=_INTERPRET)
+    out = _on_device(fused_update_kernel_call, xs, xn, we, ss, ws, wa, b,
+                     fanout=fanout, t_d=t_d, t_f=t_f, t_o=t_o)
     return out[:d, :o]
 
 
@@ -407,8 +422,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     q: [B, S, Hkv, G, D]; k/v: [B, S, Hkv, D] -> [B, S, Hkv, G, D].
     """
-    return flash_attention_call(q, k, v, q_block=q_block, pos0=pos0,
-                                interpret=_INTERPRET)
+    return _on_device(flash_attention_call, q, k, v, q_block=q_block,
+                      pos0=pos0)
 
 
 def _attn_probs(q, k, pos0):

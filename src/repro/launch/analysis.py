@@ -8,8 +8,9 @@ parsed from the HLO text are per-chip quantities:
     memory   term = bytes_per_chip / hbm_bw
     collective term = collective_operand_bytes_per_chip / link_bw
 
-Hardware constants (TPU v5e, per prompt): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants (TPU v5e, from Google Cloud's "TPU v5e" documentation):
+197 TFLOP/s bf16, 819 GB/s HBM, and 1,600 Gbit/s of inter-chip
+interconnect, i.e. 50 GB/s on each of its four links.
 """
 from __future__ import annotations
 
@@ -224,8 +225,6 @@ class Roofline:
 def roofline_from_compiled(compiled, n_chips: int, model_flops_total: float,
                            hlo_text: Optional[str] = None) -> Roofline:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

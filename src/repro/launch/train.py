@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.data.tokens import TokenPipeline
 from repro.dist import params_shardings, use_mesh
@@ -42,6 +43,7 @@ def main() -> None:
                     help="TFP window; 0 disables the two-stage prefetch")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, reduced=args.reduced)
     mesh = (make_local_mesh(model=args.model_parallel)

@@ -15,7 +15,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
 from repro.launch.dryrun import run_cell
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 out = []
 for arch, shape, policy in [("smollm-135m", "train_4k", "tp2d"),
                             ("smollm-135m", "decode_32k", "serve2d"),

@@ -100,7 +100,7 @@ def test_weighted_gradient_equivalence():
 
 
 def test_synchronizer_weighted_average():
-    sync = Synchronizer(3)
+    sync = Synchronizer(3, device=jax.devices()[0])
     g1 = {"w": jnp.ones(4)}
     g2 = {"w": 2 * jnp.ones(4)}
     g3 = {"w": 4 * jnp.ones(4)}
@@ -114,7 +114,7 @@ def test_synchronizer_weighted_average():
 
 def test_synchronizer_zero_weight_failed_trainer():
     """A failed trainer submits zero-weight grads; average unaffected."""
-    sync = Synchronizer(2)
+    sync = Synchronizer(2, device=jax.devices()[0])
     sync.submit(0, {"w": jnp.ones(2)}, 32.0)
     sync.submit(1, {"w": jnp.full((2,), 99.0)}, 0.0)
     avg = sync.all_reduce()

@@ -151,3 +151,33 @@ def test_inflight_batch_survives_share_requantize():
     assert np.isfinite(metrics["loss"])
     assert grads is not None
     tr.loader.close()
+
+
+def test_trainer_step_error_is_raised_not_hung():
+    """A trainer whose gradient step raises (a device or compile error)
+    hands the synchronizer nothing: train() raises that error instead of
+    waiting forever for the missing gradients."""
+    import threading
+    ds = _dataset()
+    tr = HybridGNNTrainer(ds, _gcfg(), HybridConfig(
+        total_batch=256, n_accel=1, hybrid=True, use_drm=False,
+        tfp_depth=0, share_quantum=32, seed=0))
+
+    def broken(*args):
+        raise RuntimeError("device step failed")
+
+    tr._grad_jit = broken
+    raised = []
+
+    def run():
+        try:
+            tr.train(1)
+        except RuntimeError as e:
+            raised.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive()
+    assert [str(e) for e in raised] == ["device step failed"]
+    tr.close()

@@ -233,9 +233,11 @@ def test_pipelined_kernels_reject_bad_depth():
         gsm.cache_combine_pipelined_kernel_call(src, base, local, depth=0)
     cache = jnp.zeros((8, 128), jnp.float32)
     rows = jnp.zeros((8, 128), jnp.float32)
-    slots = jnp.arange(8, dtype=jnp.int32)
+    mask = jnp.ones((8, 1), jnp.int32)
+    blocks = jnp.zeros((1,), jnp.int32)
     with pytest.raises(ValueError, match="depth must be >= 1"):
-        gsm.cache_update_pipelined_kernel_call(cache, rows, slots, depth=0)
+        gsm.cache_update_pipelined_kernel_call(cache, rows, mask, blocks,
+                                               depth=0)
 
 
 def test_vmem_scratch_budget():
