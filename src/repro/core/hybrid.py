@@ -110,7 +110,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -136,6 +135,7 @@ from .perfmodel import (PLATFORMS, CalibratedKnobModel, KnobBounds,
                         platform_for_device_kind)
 from .pipeline import PipelineItem, PrefetchPipeline, Stage
 from .protocol import Runtime, Synchronizer, TrainerHandle, device_of
+from .spans import span, step
 
 __all__ = ["HybridConfig", "HybridGNNTrainer", "IterationMetrics",
            "resolve_trainer_devices"]
@@ -298,10 +298,14 @@ class IterationMetrics:
     cache_hit_rate: float = 0.0       # measured (epoch-window) cache hit rate
     cache_version: int = 0            # cache version after this iteration
                                       #   (> 0 once a dynamic refresh fired)
+    t_wall: float = 0.0               # the iteration's hyscale.step span:
+                                      #   batch boundary to batch boundary
 
     @property
     def iter_time(self) -> float:
-        return self.times.iteration_time()
+        """Wall time of the iteration on the train loop's clock (the DRM
+        prices the modelled ``times.iteration_time()`` instead)."""
+        return self.t_wall
 
     @property
     def mteps(self) -> float:
@@ -648,16 +652,19 @@ class HybridGNNTrainer:
         for i, name in enumerate(names):
             tgt = p["targets"][name]
             labels = self.dataset.labels[tgt]
-            t0 = time.perf_counter()
             if i < n_accel_sampled:
-                self._sample_key, sub = jax.random.split(self._sample_key)
-                mb = self._jax_sample(sub, *self._dev_topology,
-                                      jnp.asarray(tgt), jnp.asarray(labels))
-                mb = jax.block_until_ready(mb)
-                t_sa += time.perf_counter() - t0
+                with span("hyscale.sample.accel") as s:
+                    self._sample_key, sub = jax.random.split(
+                        self._sample_key)
+                    mb = self._jax_sample(sub, *self._dev_topology,
+                                          jnp.asarray(tgt),
+                                          jnp.asarray(labels))
+                    mb = jax.block_until_ready(mb)
+                t_sa += s.seconds
             else:
-                mb = self.cpu_sampler.sample(tgt, labels)
-                t_sc += time.perf_counter() - t0
+                with span("hyscale.sample.cpu") as s:
+                    mb = self.cpu_sampler.sample(tgt, labels)
+                t_sc += s.seconds
             p["minibatch"][name] = mb
         p["t"]["t_sc"], p["t"]["t_sa"] = t_sc, t_sa
         # TFP lookahead -> background storage I/O: this batch's frontier
@@ -701,7 +708,6 @@ class HybridGNNTrainer:
     def _stage_load(self, item: PipelineItem) -> PipelineItem:
         p = item.payload
         self.loader.num_threads = self.runtime.assignment.threads.get("load", 1)
-        t0 = time.perf_counter()
         stall0 = self.loader.stats.stall_seconds \
             + self.loader.host_stats.stall_seconds
         # sharded plane: ONE union lookup + host gather covers every
@@ -732,7 +738,6 @@ class HybridGNNTrainer:
             else:
                 p["features"][name] = self.loader.load(
                     mb, to_device=(name != "cpu"))
-        p["t"]["t_load"] = time.perf_counter() - t0
         # storage-I/O stall share of the load stage (cold mmap faults the
         # prefetcher did not hide) — DRM-visible via StageTimes
         p["t"]["t_load_stall"] = (self.loader.stats.stall_seconds
@@ -760,13 +765,15 @@ class HybridGNNTrainer:
         # cheaper than the legacy full-frontier transfer
         bucket = min(-(-m // 128) * 128, look.num_rows)
         if m < bucket:
-            pad = bucket - m
-            rows = np.concatenate(
-                [rows, np.zeros((pad, rows.shape[1]), rows.dtype)], 0)
+            with span("hyscale.transfer.schedule"):
+                pad = bucket - m
+                rows = np.concatenate(
+                    [rows, np.zeros((pad, rows.shape[1]), rows.dtype)], 0)
             # padding rows cross PCIe too: keep the shipped-byte stats honest
             self.loader.note_transfer_padding(
                 pad, pad * rows.shape[1] * rows.dtype.itemsize)
-        miss = jax.device_put(rows, dev)
+        with span("hyscale.transfer.ship"):
+            miss = jax.device_put(rows, dev)
         if block.shipped is not None:
             # publish the device-resident rows for the recent-rows LRU:
             # a later batch's load stage plans against the ids/version
@@ -853,7 +860,6 @@ class HybridGNNTrainer:
 
     def _stage_transfer(self, item: PipelineItem) -> PipelineItem:
         p = item.payload
-        t0 = time.perf_counter()
         # iterate the payload's own trainer set, not _active_trainers():
         # with TFP prefetch in flight the DRM may have re-quantized a
         # share to 0 since this batch was sampled — the batch still
@@ -872,12 +878,14 @@ class HybridGNNTrainer:
             elif isinstance(feat, MissBlock):
                 x = self._assemble(feat, dev)
             else:
-                x = jax.device_put(feat, dev)
-            mb = jax.device_put(p["minibatch"][name], dev)
+                with span("hyscale.transfer.ship"):
+                    x = jax.device_put(feat, dev)
+            with span("hyscale.transfer.ship"):
+                mb = jax.device_put(p["minibatch"][name], dev)
             p["features"][name] = x
             p["minibatch"][name] = mb
-        jax.block_until_ready([p["features"][n] for n in p["features"]])
-        p["t"]["t_tran"] = time.perf_counter() - t0
+        with span("hyscale.transfer.wait"):
+            jax.block_until_ready([p["features"][n] for n in p["features"]])
         return item
 
     # ------------------------------------------------------------- training
@@ -919,7 +927,8 @@ class HybridGNNTrainer:
                 device=(self.cpu_device if kind == "cpu"
                         else self._accel_device(name)),
                 grad_fn=(self._grad_jit_cpu if kind == "cpu"
-                         else self._grad_jit), index=idx)
+                         else self._grad_jit), index=idx,
+                iteration=p["iteration"])
             weight = float(p["shares"][name])
             try:
                 metrics = handle.run(sync, self.params, weight,
@@ -1334,8 +1343,7 @@ class HybridGNNTrainer:
             out.update(self.autotuner.report())
         return out
 
-    def _apply_update(self, grads: PyTree) -> float:
-        t0 = time.perf_counter()
+    def _apply_update(self, grads: PyTree) -> None:
         if self.compression.method != "none":
             comp = compress_grads(grads, self.compression)
             grads = decompress_grads(comp, self.compression, self.params)
@@ -1343,28 +1351,23 @@ class HybridGNNTrainer:
             grads, self.opt_state, self.params)
         self.params = apply_updates(self.params, updates)
         jax.block_until_ready(self.params)
-        return time.perf_counter() - t0
 
     # ----------------------------------------------------------------- train
 
-    def train(self, num_iterations: int) -> List[IterationMetrics]:
-        stages = [Stage("sample", self._stage_sample),
-                  Stage("load", self._stage_load),
-                  Stage("transfer", self._stage_transfer)]
-        pipe = PrefetchPipeline(
-            stages, depth=self.cfg.tfp_depth,
-            watchdog_seconds=self.cfg.pipeline_watchdog_seconds,
-            fault_injector=self.fault_injector)
-        payloads = (self._make_payload(i) for i in range(num_iterations))
-
-        for item in pipe.run(payloads):
-            p = item.payload
+    def _train_step(self, item: PipelineItem) -> IterationMetrics:
+        """Train one pipelined batch, update, and do the iteration's
+        bookkeeping; the train loop's spans after ``hyscale.wait_batch``."""
+        p = item.payload
+        i = p["iteration"]
+        with span("hyscale.train", iteration=i):
             grads, ttimes, metrics = self._run_trainers(item)
-            t_sync = self._apply_update(grads)
+        with span("hyscale.update", iteration=i) as up:
+            self._apply_update(grads)
+        with span("hyscale.drm", iteration=i):
             times = StageTimes(
                 t_sa=p["t"].get("t_sa", 0.0), t_sc=p["t"].get("t_sc", 0.0),
-                t_load=p["t"].get("t_load", 0.0),
-                t_tran=p["t"].get("t_tran", 0.0),
+                t_load=item.timings.get("load", 0.0),
+                t_tran=item.timings.get("transfer", 0.0),
                 t_tc=ttimes["t_tc"], t_ta=ttimes["t_ta"],
                 t_load_stall=p["t"].get("t_load_stall", 0.0))
             # account for failures: drop trainers, DRM rebalances the rest
@@ -1395,8 +1398,8 @@ class HybridGNNTrainer:
             edges = sum(mb.edges_traversed()
                         for mb in p["minibatch"].values())
             m = IterationMetrics(
-                iteration=p["iteration"], loss=metrics["loss"],
-                acc=metrics["acc"], times=times, t_sync=t_sync, edges=edges,
+                iteration=i, loss=metrics["loss"], acc=metrics["acc"],
+                times=times, t_sync=up.seconds, edges=edges,
                 assignment=self.runtime.quantized_shares(),
                 grad_devices=metrics["grad_devices"],
                 cache_hit_rate=(self.cache.measured_hit_rate()
@@ -1404,8 +1407,31 @@ class HybridGNNTrainer:
                 cache_version=self.cache.version if self.cache else 0)
             self.history.append(m)
             if (self.cfg.ckpt_every and self._ckpt_cb
-                    and (p["iteration"] + 1) % self.cfg.ckpt_every == 0):
-                self._ckpt_cb(p["iteration"], self.params, self.opt_state)
+                    and (i + 1) % self.cfg.ckpt_every == 0):
+                self._ckpt_cb(i, self.params, self.opt_state)
+        return m
+
+    def train(self, num_iterations: int) -> List[IterationMetrics]:
+        stages = [Stage("sample", self._stage_sample),
+                  Stage("load", self._stage_load),
+                  Stage("transfer", self._stage_transfer)]
+        pipe = PrefetchPipeline(
+            stages, depth=self.cfg.tfp_depth,
+            watchdog_seconds=self.cfg.pipeline_watchdog_seconds,
+            fault_injector=self.fault_injector)
+        payloads = (self._make_payload(i) for i in range(num_iterations))
+        batches = pipe.run(payloads)
+        for i in range(num_iterations):
+            with step("hyscale.step", step_num=i) as st:
+                with span("hyscale.wait_batch", iteration=i):
+                    item = next(batches, None)
+                if item is None:        # the batch source ended early
+                    break
+                m = self._train_step(item)
+            m.t_wall = st.seconds
+        # the pipeline ends after its last batch: join its threads and
+        # surface a stage failure
+        next(batches, None)
         # a background failure after the last iteration boundary (final
         # staged gather, final prefetch) would otherwise vanish
         self._raise_background_errors()

@@ -11,12 +11,14 @@ PCIe channel, device compute) and mini-batches are independent.  Disabling
 TFP (``depth=0``) degenerates to sequential stage execution — that is the
 ablation baseline of Fig. 11.
 
-Every item carries a ``timings`` dict; each stage records its service time,
-which the Runtime feeds to the DRM engine.  Pipelined stages additionally
-record ``<stage>_wait`` — the time the worker sat starved on its input
-queue before this item arrived (0 in sequential mode).  Wait times are
-the pipeline-level stall signal: a stage whose upstream is the bottleneck
-shows large waits, a stage that IS the bottleneck shows none.
+Every item carries a ``timings`` dict; each stage call is timed once, as
+the span ``hyscale.<stage>`` (``core.spans``, carrying the item's
+``iteration=``), and its seconds are the stage's service time, which the
+Runtime feeds to the DRM engine.  A pipelined stage's wait on its input
+queue is the span ``hyscale.<stage>.starved``: the pipeline-level stall
+signal, seen on a profiler trace — a stage whose upstream is the
+bottleneck shows long starved spans, a stage that IS the bottleneck shows
+none.
 
 Failure model & degraded modes
 ------------------------------
@@ -44,6 +46,8 @@ import queue
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
+
+from .spans import span
 
 __all__ = ["PipelineItem", "Stage", "PrefetchPipeline", "PipelineStallError"]
 
@@ -118,10 +122,17 @@ class PrefetchPipeline:
             for st in self.stages:
                 if self.fault_injector is not None:
                     self.fault_injector.fire(f"pipeline.{st.name}")
-                t0 = time.perf_counter()
-                item = st.fn(item)
-                item.timings[st.name] = time.perf_counter() - t0
+                item = self._call(st, item)
             yield item
+
+    @staticmethod
+    def _call(st: Stage, item: PipelineItem) -> PipelineItem:
+        """One stage call, timed once: span ``hyscale.<stage>``, whose
+        seconds become ``timings[<stage>]``."""
+        with span("hyscale." + st.name, iteration=item.seq) as s:
+            item = st.fn(item)
+        item.timings[st.name] = s.seconds
+        return item
 
     # ------------------------------------------------------------- pipelined
 
@@ -129,26 +140,23 @@ class PrefetchPipeline:
                 state: Dict[str, Optional[BaseException]],
                 stop: threading.Event, hb: Dict[str, Any]):
         failed = False
+        starved = f"hyscale.{st.name}.starved"
         while True:
-            t_wait = time.perf_counter()
-            item = q_in.get()
-            wait = time.perf_counter() - t_wait
+            with span(starved):
+                item = q_in.get()
             if item is _SENTINEL:
                 q_out.put(_SENTINEL)
                 return
             if failed or stop.is_set():
                 continue            # drain so the feeder never blocks
             try:
-                item.timings[st.name + "_wait"] = wait
                 # heartbeat: the watchdog reads (busy, since) to tell a
                 # wedged stage from an idle one
                 hb["since"] = time.perf_counter()
                 hb["busy"] = True
                 if self.fault_injector is not None:
                     self.fault_injector.fire(f"pipeline.{st.name}")
-                t0 = time.perf_counter()
-                item = st.fn(item)
-                item.timings[st.name] = time.perf_counter() - t0
+                item = self._call(st, item)
                 hb["busy"] = False
                 hb["done"] += 1
             except BaseException as e:  # propagate to consumer

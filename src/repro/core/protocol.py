@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -31,6 +30,7 @@ import numpy as np
 from repro.analysis.annotations import guarded_by
 
 from .drm import Assignment, DRMEngine, StageTimes
+from .spans import span
 
 __all__ = ["Synchronizer", "TrainerHandle", "Runtime", "device_of"]
 
@@ -62,7 +62,9 @@ class Synchronizer:
         shares is algorithmically identical to single-device large-batch
         SGD (paper Section II-B).  Every trainer's gradients are first
         brought to ``device`` (the authoritative parameters' device):
-        trainers on different devices cannot be summed in place.
+        trainers on different devices cannot be summed in place.  The
+        averaging, after the last trainer reported, is the span
+        ``hyscale.sync``.
         """
         with self._cond:
             while self._done != self.n_trainers:       # Listing 1 line 24
@@ -70,13 +72,14 @@ class Synchronizer:
             slots = list(self._slots)                  # gather_data()
             self._done = 0
             self._slots = [None] * self.n_trainers
-        total_w = sum(w for _, w in slots)
-        slots = [(jax.device_put(g, self.device), w) for g, w in slots]
-        scaled = [jax.tree.map(lambda g: g * (w / total_w), g)
-                  for g, w in slots]
-        avg = scaled[0]
-        for s in scaled[1:]:                            # average_gradients()
-            avg = jax.tree.map(lambda a, b: a + b, avg, s)
+        with span("hyscale.sync"):
+            total_w = sum(w for _, w in slots)
+            slots = [(jax.device_put(g, self.device), w) for g, w in slots]
+            scaled = [jax.tree.map(lambda g: g * (w / total_w), g)
+                      for g, w in slots]
+            avg = scaled[0]
+            for s in scaled[1:]:                        # average_gradients()
+                avg = jax.tree.map(lambda a, b: a + b, avg, s)
         return avg
 
 
@@ -88,17 +91,20 @@ class TrainerHandle:
     device: Any                  # jax.Device
     grad_fn: Callable[..., Tuple[PyTree, Dict[str, Any]]]
     index: int
+    iteration: int = 0           # the batch's, carried by the span
 
     def run(self, sync: Synchronizer, params: PyTree, weight: float,
             *args: Any) -> Dict[str, Any]:
-        t0 = time.perf_counter()
-        params = jax.device_put(params, self.device)
-        grads, metrics = self.grad_fn(params, *args)
-        grads = jax.block_until_ready(grads)
-        dt = time.perf_counter() - t0
+        """One training step, the span ``hyscale.train.<kind>``, whose
+        seconds are ``metrics["t_train"]``; then DONE++."""
+        with span("hyscale.train." + self.kind,
+                  iteration=self.iteration) as s:
+            params = jax.device_put(params, self.device)
+            grads, metrics = self.grad_fn(params, *args)
+            grads = jax.block_until_ready(grads)
         sync.submit(self.index, grads, weight)          # DONE++, signal
         metrics = dict(metrics)
-        metrics["t_train"] = dt
+        metrics["t_train"] = s.seconds
         dev = device_of(grads)
         metrics["device"] = f"{dev.platform}:{dev.id}"
         return metrics

@@ -72,6 +72,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.analysis.annotations import guarded_by
+from repro.core.spans import span
 
 from .featcache import (CacheLookup, FeatureCache, ShardLookup,
                         compact_lookup, wire_row_bytes)
@@ -315,17 +316,19 @@ class FeatureLoader:
         return chunks, order
 
     def _gather(self, rows: np.ndarray) -> np.ndarray:
-        if self.num_threads == 1 or rows.shape[0] < 2 * self.num_threads:
-            return self.source.take(rows)
-        # chunked gather: with >1 OS threads numpy gathers overlap page faults
-        chunks, order = self._split_chunks(rows)
-        parts = list(self._get_pool().map(self.source.take, chunks))
-        gathered = np.concatenate(parts, axis=0)
-        if order is None:
-            return gathered
-        out = np.empty_like(gathered)
-        out[order] = gathered      # scatter back into request order
-        return out
+        """Every host row gather: the span ``hyscale.load.gather``."""
+        with span("hyscale.load.gather"):
+            if self.num_threads == 1 or rows.shape[0] < 2 * self.num_threads:
+                return self.source.take(rows)
+            # chunked gather: with >1 OS threads numpy gathers overlap faults
+            chunks, order = self._split_chunks(rows)
+            parts = list(self._get_pool().map(self.source.take, chunks))
+            gathered = np.concatenate(parts, axis=0)
+            if order is None:
+                return gathered
+            out = np.empty_like(gathered)
+            out[order] = gathered      # scatter back into request order
+            return out
 
     def _cast(self, x: np.ndarray) -> np.ndarray:
         if self.transfer_dtype == "bfloat16":
@@ -452,14 +455,16 @@ class FeatureLoader:
         stall0 = self._source_stall()
         frontier = self._frontier(batch)
         if self.cache is not None:
-            look = self.cache.lookup(frontier, dedup=self.dedup,
-                                     record=False, pin=pin)
+            with span("hyscale.load.lookup"):
+                look = self.cache.lookup(frontier, dedup=self.dedup,
+                                         record=False, pin=pin)
             row_bytes = self.cache.row_bytes
         else:
             if not self.dedup:
                 raise RuntimeError(
                     "load_compact without a FeatureCache requires dedup")
-            look = compact_lookup(frontier)
+            with span("hyscale.load.lookup"):
+                look = compact_lookup(frontier)
             row_bytes = self._row_bytes
         use_recent = (recent_key is not None and self.recent_batches > 0
                       and self.dedup)
@@ -528,8 +533,9 @@ class FeatureLoader:
         t0 = time.perf_counter()
         stall0 = self._source_stall()
         frontiers = {name: self._frontier(b) for name, b in batches.items()}
-        union = cache.lookup_union(frontiers, ordinals, pin=pin,
-                                   record=False)
+        with span("hyscale.load.lookup"):
+            union = cache.lookup_union(frontiers, ordinals, pin=pin,
+                                       record=False)
         fresh_sets = [sl.look.miss_ids
                       for sl in union.per_trainer.values()
                       if sl.look.miss_ids.shape[0]]

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
+
 from . import ref
 from .flash_attention import flash_attention_call
 from .gather_scatter_mm import (cache_combine_pipelined_kernel_call,
@@ -184,10 +186,23 @@ def _assemble_tiled(cache: Optional[jax.Array], miss: jax.Array,
     VMEM window.  The kernel writes sorted rows; one XLA take un-permutes
     (each positional row is produced exactly once, a bandwidth-bound
     copy).  All schedule tables are cheap O(N log N) host numpy, part of
-    the load stage like the paper's edge sorting.
+    the load stage like the paper's edge sorting.  The tables are the span
+    ``hyscale.transfer.schedule``, the call into the device program the
+    span ``hyscale.transfer.dispatch``.
     """
+    with span("hyscale.transfer.schedule"):
+        tables, w, t_f = _tiled_schedule(slots, miss_index,
+                                         int(miss.shape[1]))
+    with span("hyscale.transfer.dispatch"):
+        return _assemble_tiled_device(cache, miss, *tables, w=w, t_f=t_f,
+                                      depth=depth)
+
+
+def _tiled_schedule(slots: np.ndarray, miss_index: np.ndarray, f: int
+                    ) -> Tuple[Tuple[np.ndarray, ...], int, int]:
+    """The tiled combine's host tables ``(hit_table, miss_table, base,
+    local, inv)`` and its row and column tiles ``w``, ``t_f``."""
     n = int(slots.shape[0])
-    f = int(miss.shape[1])
     hit = slots >= 0
     w = _pick_tile(n, 128)
     t_f = _pick_tile(f)
@@ -221,8 +236,7 @@ def _assemble_tiled(cache: Optional[jax.Array], miss: jax.Array,
     assert local.max(initial=0) < 4 * w, "tiled combine window overflow"
     inv = np.empty(n, np.int32)     # permutation inverse via O(N) scatter
     inv[order] = np.arange(n, dtype=np.int32)
-    return _assemble_tiled_device(cache, miss, hit_table, miss_table, base,
-                                  local, inv, w=w, t_f=t_f, depth=depth)
+    return (hit_table, miss_table, base, local, inv), w, t_f
 
 
 @functools.partial(jax.jit, static_argnames=("w", "t_f", "depth"))

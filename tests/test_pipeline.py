@@ -33,8 +33,9 @@ def test_stage_timings_recorded():
     pipe = PrefetchPipeline(_stages(), depth=2)
     names = {"sample", "load", "transfer"}
     for it in pipe.run(_items(3)):
-        # service time per stage plus the queue-wait (starvation) stall
-        assert set(it.timings) == names | {n + "_wait" for n in names}
+        # one service time per stage; a stage's wait on its input queue is
+        # a span on the trace (hyscale.<stage>.starved), not a timing
+        assert set(it.timings) == names
         assert all(t >= 0 for t in it.timings.values())
 
 
