@@ -5,8 +5,8 @@ paper's full widths — f0 = 100, hidden 256, 47 classes, fanouts (25, 10),
 batch 1024 — on the full-size synthetic ogbn-products graph (2.45M
 nodes and about 61M edges, generated from ``--seed``), through
 ``HybridGNNTrainer``, its normal entry point: the CPU trainer on the host
-CPU, accel0 on the TPU, a 20% hot-feature cache on the device and the
-Pallas combine kernel assembling the layer-0 input.  Weights are random.
+CPU, accel0 on the TPU, a 20% hot-feature cache on the device and XLA's
+gather assembling the layer-0 input.  Weights are random.
 
     python chip_smoke.py              # one chip: the hybrid trainer
     python chip_smoke.py --chips 4    # four chips: n_accel=4 with the
@@ -15,8 +15,10 @@ Pallas combine kernel assembling the layer-0 input.  Weights are random.
 
 Checks: every loss is finite; accel trainers' gradients come from
 distinct TPUs and the CPU trainer's (when it has a share) from the host
-CPU; on one real batch the compiled Pallas combine equals
-``kernels/ref.py``'s ``assemble_features`` exactly; ``health()`` is ok;
+CPU; the trainer picks XLA's gather for the combine and the Pallas
+cache-update kernel; on one real batch the Pallas combine, forced and
+compiled, equals ``kernels/ref.py``'s ``assemble_features`` exactly;
+``health()`` is ok;
 with ``--chips 4`` the sharded and replicated planes give the same
 losses.  The last line of the output is one JSON object naming the device.
 Without a TPU the script exits non-zero and prints no result.
@@ -176,7 +178,10 @@ def one_chip_phase(ds, gnn, batch: int, steps: int, seed: int,
           f"{tr.runtime.quantized_shares()}", flush=True)
     try:
         if accel_platform == "tpu":
-            check(tr._assemble_pallas, "the trainer picked the jnp combine")
+            check(not tr._assemble_pallas,
+                  "the trainer picked the Pallas combine, not XLA's gather")
+            check(tr.cache.use_pallas_update,
+                  "the trainer picked the jnp cache update, not Pallas")
         hist = train_steps(tr, steps, clock, "1-chip")
         check_grad_devices(hist, accel_platform, "1-chip")
         if not any("cpu" in m.grad_devices for m in hist):

@@ -97,10 +97,10 @@ def main():
     ap.add_argument("--cache-assemble", default="auto",
                     choices=["auto", "jnp", "pallas"],
                     help="device-side cache+miss combine path: 'auto' "
-                         "picks pallas when the accelerators are TPUs and "
-                         "jnp on host stand-ins; force 'pallas' to "
-                         "exercise the (interpret-mode) kernels off-TPU, "
-                         "e.g. with a pipeline depth")
+                         "and 'jnp' take XLA's gather ('auto' keeps the "
+                         "Pallas cache-update kernel on TPUs); 'pallas' "
+                         "forces the tiled combine kernel, interpreted "
+                         "off-TPU, e.g. with a pipeline depth")
     ap.add_argument("--kernel-pipeline-depth", type=int, default=1,
                     help="Pallas combine/scatter DMA pipeline depth: 1 = "
                          "single-buffered, 2-4 = multi-buffered "

@@ -193,7 +193,11 @@ class HybridConfig:
                                       #   stay addressable on the device and
                                       #   are not re-shipped (0 = off;
                                       #   replicated/dedup path only)
-    cache_assemble: str = "auto"      # "auto" | "jnp" | "pallas" combine path
+    cache_assemble: str = "auto"      # "auto" | "jnp" | "pallas": "pallas"
+                                      #   forces the tiled combine kernel,
+                                      #   the others take XLA's gather;
+                                      #   "auto" keeps the Pallas cache
+                                      #   update on a TPU
     kernel_pipeline_depth: int = 1    # Pallas combine/scatter DMA pipeline
                                       #   depth: 1 = single-buffered, 2..4 =
                                       #   multi-buffered DMA/compute overlap
@@ -438,14 +442,17 @@ class HybridGNNTrainer:
         self._refresh_thread: Optional[threading.Thread] = None
         self._refresh_error: Optional[BaseException] = None
         self._staged_feedback: Optional[Tuple[float, float]] = None
-        self._assemble_pallas = (cfg.cache_assemble == "pallas"
-                                 or (cfg.cache_assemble == "auto"
-                                     and any(d.platform == "tpu" for d in
-                                             self.accel_devices)))
+        # the combine is XLA's gather and select unless "pallas" forces the
+        # tiled kernel: that kernel needs a host sort over every position
+        # per batch, which costs more than the kernel saves on a TPU
+        self._assemble_pallas = cfg.cache_assemble == "pallas"
         if self.cache is not None:
             if fault_injector is not None:
                 self.cache.fault_injector = fault_injector
-            self.cache.use_pallas_update = self._assemble_pallas
+            self.cache.use_pallas_update = (
+                self._assemble_pallas
+                or (cfg.cache_assemble == "auto"
+                    and any(d.platform == "tpu" for d in self.accel_devices)))
             self.cache.kernel_pipeline_depth = cfg.kernel_pipeline_depth
             # hotness tracking costs two scattered adds per lookup and a
             # 4 B/node estimate array: only pay it when the refresh policy
@@ -800,10 +807,13 @@ class HybridGNNTrainer:
             # releasing the pin here lets a fully-drained old version
             # retire its [K, F] snapshots immediately
             self.cache.release_lookup(look)
-        # slots / miss_index stay host numpy: the Pallas path derives its
-        # DMA schedule from them before they ever reach the device
-        return assemble_features(cache_data, miss, look.slots,
-                                 look.miss_index,
+        slots, miss_index = look.slots, look.miss_index
+        if not self._assemble_pallas:
+            # XLA's gather indexes the tables on the device; the Pallas
+            # path keeps them host numpy to derive its DMA schedule first
+            with span("hyscale.transfer.ship"):
+                slots, miss_index = jax.device_put((slots, miss_index), dev)
+        return assemble_features(cache_data, miss, slots, miss_index,
                                  use_pallas=self._assemble_pallas,
                                  pipeline_depth=self.cfg
                                  .kernel_pipeline_depth)

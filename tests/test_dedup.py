@@ -67,24 +67,47 @@ def test_cached_compact_lookup_round_trip(size, capacity, seed):
 # ------------------------------------------------- tiled kernel parity
 
 
-@pytest.mark.parametrize("k,m,n,f", [
-    (31, 9, 57, 12),      # everything ragged
-    (64, 1, 1, 100),      # single output row
-    (1, 3, 8, 8),         # tiny cache
-    (200, 7, 129, 257),   # odd feature dim, n just past a tile
-    (128, 128, 512, 128), # fully tile-aligned
-])
-def test_tiled_combine_matches_ref_and_legacy_kernel(k, m, n, f):
+def _combine_case(k, m, n, f, hits):
+    """A cache of ``k`` rows, ``m`` miss rows and ``n`` positions that
+    hit the cache ("all-hit"), read the miss rows ("all-miss") or either
+    ("mixed"); past ``k + m`` positions many share one row."""
     rng = np.random.default_rng(n * 7 + f)
     cache = jnp.asarray(rng.normal(size=(k, f)), jnp.float32)
     miss = jnp.asarray(rng.normal(size=(m, f)), jnp.float32)
-    slots = rng.integers(-1, k, size=n).astype(np.int32)
+    lo = 0 if hits == "all-hit" else -1
+    hi = 0 if hits == "all-miss" else k
+    slots = rng.integers(lo, hi, size=n).astype(np.int32)
     mi = np.where(slots < 0, rng.integers(0, m, size=n), 0).astype(np.int32)
+    return cache, miss, slots, mi
+
+
+@pytest.mark.parametrize("k,m,n,f,hits", [
+    # everything ragged
+    pytest.param(31, 9, 57, 12, "mixed", id="31-9-57-12"),
+    # single output row
+    pytest.param(64, 1, 1, 100, "mixed", id="64-1-1-100"),
+    # tiny cache
+    pytest.param(1, 3, 8, 8, "mixed", id="1-3-8-8"),
+    # odd feature dim, n just past a tile
+    pytest.param(200, 7, 129, 257, "mixed", id="200-7-129-257"),
+    # fully tile-aligned
+    pytest.param(128, 128, 512, 128, "mixed", id="128-128-512-128"),
+    # many positions to one row: every position hits, misses or either
+    pytest.param(24, 6, 700, 100, "all-hit", id="many-to-one-all-hit"),
+    pytest.param(24, 6, 700, 100, "all-miss", id="many-to-one-all-miss"),
+    pytest.param(24, 6, 700, 100, "mixed", id="many-to-one-mixed"),
+])
+def test_tiled_combine_matches_ref_and_legacy_kernel(k, m, n, f, hits):
+    """The Pallas combine (interpreted), the jnp combine (XLA's gather and
+    select, the trainer's default) and the oracle agree bit for bit."""
+    cache, miss, slots, mi = _combine_case(k, m, n, f, hits)
     a = ref.assemble_features(cache, miss, jnp.asarray(slots),
                               jnp.asarray(mi))
     b = ops.assemble_features(cache, miss, jnp.asarray(slots),
                               jnp.asarray(mi), use_pallas=True)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+    x = ops.assemble_features(cache, miss, slots, mi)
+    assert np.array_equal(np.asarray(x), np.asarray(b))
     # the retired one-row-per-grid-step kernel is the parity baseline
     sel = (slots < 0).astype(np.int32)
     row = np.where(slots < 0, mi, slots).astype(np.int32)
@@ -103,6 +126,36 @@ def test_tiled_combine_duplicated_rows_and_no_cache():
                                 jnp.asarray(inverse), use_pallas=True)
     assert np.array_equal(np.asarray(out),
                           np.asarray(ref.expand_rows(rows, inverse)))
+    x = ops.assemble_features(None, rows, slots, inverse)
+    assert np.array_equal(np.asarray(x), np.asarray(out))
+
+
+def test_jnp_combine_ignores_the_distinct_hit_count():
+    """The jnp combine's program is keyed by the cache shape, the miss
+    block's rows and the position count: two batches that differ only in
+    how many distinct cache rows they hit share it, where the Pallas path
+    buckets the distinct hits and builds a second program."""
+    k, m, n, f = 400, 128, 600, 16
+    rng = np.random.default_rng(3)
+    cache = jnp.asarray(rng.normal(size=(k, f)), jnp.float32)
+    miss = jnp.asarray(rng.normal(size=(m, f)), jnp.float32)
+    batches = []
+    for distinct in (5, 300):
+        slots = rng.choice(distinct, size=n).astype(np.int32)
+        slots[::4] = -1
+        mi = np.where(slots < 0, rng.integers(0, m, size=n), 0
+                      ).astype(np.int32)
+        assert np.unique(slots[slots >= 0]).shape[0] > distinct // 2
+        batches.append((slots, mi))
+    ops.assemble_features(cache, miss, *batches[0])
+    jnp_programs = ops._assemble_ref._cache_size()
+    tiled_programs = ops._assemble_tiled_device._cache_size()
+    for slots, mi in batches:
+        a = ops.assemble_features(cache, miss, slots, mi)
+        b = ops.assemble_features(cache, miss, slots, mi, use_pallas=True)
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert ops._assemble_ref._cache_size() == jnp_programs
+    assert ops._assemble_tiled_device._cache_size() == tiled_programs + 2
 
 
 def test_tiled_combine_bf16_bit_identical():

@@ -149,9 +149,10 @@ def test_model_entry_points_pick_the_compiled_kernel(one_chip, f0):
 @pytest.mark.parametrize("f0", [100, 756])
 @pytest.mark.parametrize("depth", [1, 2])
 def test_trainer_combine_path_compiles(one_chip, f0, depth):
-    """The trainer's whole combine (host schedule, compaction, kernel,
-    un-permute) for a real batch layout: 20% of positions served by the
-    hot cache, the rest by deduplicated shipped rows."""
+    """The whole Pallas combine that ``cache_assemble="pallas"`` forces
+    (host schedule, compaction, kernel, un-permute) for a real batch
+    layout: 20% of positions served by the hot cache, the rest by
+    deduplicated shipped rows."""
     rng = np.random.default_rng(0)
     miss_rows = POSITIONS // 4
     slots = np.where(rng.random(POSITIONS) < 0.2,
@@ -162,3 +163,19 @@ def test_trainer_combine_path_compiles(one_chip, f0, depth):
         c, m, slots, miss_index, use_pallas=True, pipeline_depth=depth),
         one_chip, ((CACHE_ROWS, f0), jnp.float32),
         ((miss_rows, f0), jnp.float32))
+
+
+@pytest.mark.parametrize("f0", [100, 756])
+def test_xla_combine_compiles(one_chip, f0):
+    """The trainer's default combine, XLA's gather and select, at the same
+    real layout with the index tables on the device: no Mosaic kernel,
+    and it fits one chip."""
+    miss_rows = POSITIONS // 4
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((CACHE_ROWS, f0), jnp.float32), ((miss_rows, f0), jnp.float32),
+        ((POSITIONS,), jnp.int32), ((POSITIONS,), jnp.int32))]
+    compiled = ops._assemble_ref.lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16 * 2**30

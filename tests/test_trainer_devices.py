@@ -117,3 +117,40 @@ def test_synchronizer_without_device_sums_in_place():
     avg = sync.all_reduce()["w"]
     assert avg.devices() == {dev}
     np.testing.assert_array_equal(np.asarray(avg), np.full(2, 2.0))
+
+
+class _ReadsTpu:
+    """A host device whose platform reads as a v5e's: the trainer's
+    routing sees a TPU, and whatever it places still lands on the host."""
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+    def __init__(self, dev):
+        self._dev = dev
+
+    def __getattr__(self, name):
+        return getattr(self._dev, name)
+
+
+@pytest.mark.parametrize("assemble,pallas_combine", [("auto", False),
+                                                     ("pallas", True)])
+def test_combine_routing_on_a_tpu(monkeypatch, assemble, pallas_combine):
+    """On a TPU, "auto" takes XLA's gather for the combine and keeps the
+    Pallas cache-update kernel; "pallas" forces both kernels."""
+    from repro.core import HybridConfig, HybridGNNTrainer, hybrid
+    from repro.graph import GNNConfig, make_dataset
+    monkeypatch.setattr(
+        hybrid, "resolve_trainer_devices",
+        lambda n, cpus, accels: (cpus[0], [_ReadsTpu(cpus[0])] * n))
+    ds = make_dataset("ogbn-products", scale=0.002, seed=0)
+    g = GNNConfig(model="sage", layer_dims=(100, 32, 47), fanouts=(4, 3),
+                  num_classes=47)
+    tr = HybridGNNTrainer(ds, g, HybridConfig(
+        total_batch=128, n_accel=1, hybrid=True, use_drm=False,
+        cache_fraction=0.2, cache_assemble=assemble, seed=0))
+    try:
+        assert tr.accel_devices[0].platform == "tpu"
+        assert tr._assemble_pallas is pallas_combine
+        assert tr.cache.use_pallas_update is True
+    finally:
+        tr.close()
