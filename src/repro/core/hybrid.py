@@ -125,7 +125,7 @@ from repro.graph import (FeatureLoader, GNNConfig, GraphDataset, MiniBatch,
                          compact_lookup, init_params, loss_fn,
                          sample_minibatch_jax)
 from repro.kernels.ops import assemble_features, assemble_features_sharded
-from repro.optim import (CompressionSpec, adamw, compress_grads,
+from repro.optim import (CompressionSpec, Optimizer, adamw, compress_grads,
                          decompress_grads)
 from repro.optim.optimizers import apply_updates
 
@@ -330,6 +330,17 @@ def _grad_fn(gnn_cfg: GNNConfig) -> Callable:
     return jax.jit(_grad)
 
 
+def _compiled_update(optimizer: Optimizer) -> Callable:
+    """``(params, opt_state, grads) -> (params, opt_state)``: the
+    optimizer's update and ``apply_updates`` as one program, params and
+    state donated (one dispatch, not one per operation and leaf)."""
+    def _update(params, opt_state, grads):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return apply_updates(params, updates), opt_state
+
+    return jax.jit(_update, donate_argnums=(0, 1))
+
+
 # Deliberately UNGUARDED shared state: _fail_at (written once before the
 # run by the failure-injection test hook, read-only during it),
 # _refresh_failures / _refresh_disabled / _staged_feedback /
@@ -373,10 +384,15 @@ class HybridGNNTrainer:
                 self.accel_devices[0].device_kind)
 
         # --- parameters / optimizer (single authoritative copy) -------------
+        # committed to their device, as the compiled update returns them,
+        # so that its first call builds the program every later call reuses
         key = jax.random.PRNGKey(cfg.seed)
-        self.params = init_params(key, gnn_cfg)
+        params = init_params(key, gnn_cfg)
+        home = device_of(params)
+        self.params = jax.device_put(params, home)
         self.optimizer = adamw(cfg.lr)
-        self.opt_state = self.optimizer.init(self.params)
+        self.opt_state = jax.device_put(self.optimizer.init(params), home)
+        self._update_step = _compiled_update(self.optimizer)
         self.compression = CompressionSpec(cfg.compression)
 
         # --- samplers --------------------------------------------------------
@@ -1361,9 +1377,8 @@ class HybridGNNTrainer:
         if self.compression.method != "none":
             comp = compress_grads(grads, self.compression)
             grads = decompress_grads(comp, self.compression, self.params)
-        updates, self.opt_state = self.optimizer.update(
-            grads, self.opt_state, self.params)
-        self.params = apply_updates(self.params, updates)
+        self.params, self.opt_state = self._update_step(
+            self.params, self.opt_state, grads)
         jax.block_until_ready(self.params)
 
     # ----------------------------------------------------------------- train

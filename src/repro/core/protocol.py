@@ -25,6 +25,7 @@ import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.annotations import guarded_by
@@ -74,13 +75,24 @@ class Synchronizer:
             self._slots = [None] * self.n_trainers
         with span("hyscale.sync"):
             total_w = sum(w for _, w in slots)
-            slots = [(jax.device_put(g, self.device), w) for g, w in slots]
-            scaled = [jax.tree.map(lambda g: g * (w / total_w), g)
-                      for g, w in slots]
-            avg = scaled[0]
-            for s in scaled[1:]:                        # average_gradients()
-                avg = jax.tree.map(lambda a, b: a + b, avg, s)
+            trees = [jax.device_put(g, self.device) for g, _ in slots]
+            shares = np.asarray([w / total_w for _, w in slots], np.float32)
+            avg = _weighted_sum(trees, shares)          # average_gradients()
         return avg
+
+
+@jax.jit
+def _weighted_sum(trees: List[PyTree], shares: jax.Array) -> PyTree:
+    """``Σ trees[i] · shares[i]``, summed in trainer order, as one program.
+    The shares are traced, so new mini-batch shares (or a dead trainer's
+    0) reuse it; only another trainer count or tree structure builds
+    another."""
+    avg = None
+    for i, tree in enumerate(trees):
+        w = shares[i]
+        scaled = jax.tree.map(lambda g: g * w.astype(g.dtype), tree)
+        avg = scaled if avg is None else jax.tree.map(jnp.add, avg, scaled)
+    return avg
 
 
 @dataclasses.dataclass

@@ -212,3 +212,34 @@ def test_gat_train_step_compiles(one_chip):
             + mem.temp_size_in_bytes) < 16 * 2**30
     text = compiled.as_text()
     assert "gat.project" in text and "gat.attention" in text
+
+
+@pytest.mark.parametrize("model,dims,heads", [
+    ("sage", (100, 256, 47), 1), ("gat", (100, 512, 512, 47), 4)])
+def test_sync_and_update_compile(one_chip, model, dims, heads):
+    """The loop's two programs after the trainers, at the benchmark's
+    widths: the weighted mean of two trainers' gradients
+    (``core/protocol.py`` ``_weighted_sum``, shares traced) and the AdamW
+    step (``core/hybrid.py`` ``_compiled_update``), whose params and
+    optimizer state are donated: each of their leaves aliases an output."""
+    from repro.core.hybrid import _compiled_update
+    from repro.core.protocol import _weighted_sum
+    from repro.graph import GNNConfig, init_params
+    from repro.optim import adamw
+    cfg = GNNConfig(model=model, layer_dims=dims,
+                    fanouts=(10,) * (len(dims) - 1), num_classes=47,
+                    heads=heads)
+    opt = adamw(1e-3)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = jax.tree.map(sds, jax.eval_shape(opt.init, params))
+    shares = jax.ShapeDtypeStruct((2,), jnp.float32, sharding=one_chip)
+    _weighted_sum.lower([params, params], shares).compile()
+    text = _compiled_update(opt).lower(params, state, params).compile() \
+        .as_text()
+    n_donated = len(jax.tree.leaves((params, state)))
+    assert text.split("\n", 1)[0].count("may-alias") == n_donated
