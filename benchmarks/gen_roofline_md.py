@@ -1,12 +1,8 @@
 """Generate the EXPERIMENTS.md §Roofline / §Dry-run markdown tables from
-dry-run JSON results, plus the §Kernel overlap table from the
-``bench_kernel_overlap`` depth-sweep JSON (detected by shape: the
-dry-run files are lists, ``BENCH_kernel_overlap.json`` is a dict with
-``combine``/``update`` sweeps).
+dry-run JSON results (lists of per-cell records).
 
     PYTHONPATH=src python -m benchmarks.gen_roofline_md \
-        dryrun_single.json dryrun_multi.json BENCH_kernel_overlap.json \
-        > roofline_tables.md
+        dryrun_single.json dryrun_multi.json > roofline_tables.md
 """
 from __future__ import annotations
 
@@ -51,48 +47,6 @@ def table(results, title):
     return "\n".join(out)
 
 
-def _overlap_rows(rows, kind):
-    out = [f"#### {kind} kernel", "",
-           "| config | dtype | depth | us | GB/s | roofline_frac | "
-           "VMEM scratch | == depth-1 | == oracle |",
-           "|---|---|---|---|---|---|---|---|---|"]
-    for r in rows:
-        if kind == "combine":
-            cfg = (f"n={r['n']} f={r['f']} "
-                   f"tile {r['t_n']}x{r['t_f']}")
-        else:
-            cfg = (f"k={r['k']} f={r['f']} m={r['m']}"
-                   f"{' aliased' if r.get('aliased') else ''}")
-        d1 = r.get("bit_identical_vs_depth1")
-        out.append(
-            f"| {cfg} | {r['dtype']} | {r['depth']} | {r['us']:.1f} | "
-            f"{r['achieved_gbps']:.2f} | "
-            f"{r.get('roofline_fraction', 0.0):.3f} | "
-            f"{r['vmem_scratch_bytes']/1024:.0f} KiB | "
-            f"{'-' if d1 is None else ('Y' if d1 else 'N')} | "
-            f"{'Y' if r['bit_identical_vs_oracle'] else 'N'} |")
-    out.append("")
-    return out
-
-
-def overlap_table(res, title):
-    """§Kernel overlap: the bench_kernel_overlap depth sweep — wall time
-    and achieved bandwidth per (kernel x tile x feature width x dtype x
-    depth), with the bit-identity columns the tier-1 gate asserts."""
-    out = [f"### {title}", "",
-           f"Memory roofline (calibrated container): "
-           f"{res['roofline_mem_gbps']:.1f} GB/s; VMEM scratch budget "
-           f"{res['vmem_budget_bytes']/2**20:.0f} MiB.", ""]
-    out += _overlap_rows(res["combine"], "combine")
-    out += _overlap_rows(res["update"], "update")
-    if "e2e_loss_bit_identical" in res:
-        out.append(f"End-to-end trainer losses across pipeline depths "
-                   f"{res.get('e2e_depths')}: "
-                   f"{'bit-identical' if res['e2e_loss_bit_identical'] else 'DIVERGED'}.")
-        out.append("")
-    return "\n".join(out)
-
-
 def main():
     parts = []
     for path in sys.argv[1:]:
@@ -110,10 +64,6 @@ def main():
             parts.append(f"### {path}\n\n_Skipped: {path} is not valid "
                          f"JSON ({e})._\n")
             continue
-        if isinstance(results, dict) and "combine" in results:
-            parts.append(overlap_table(results,
-                                       f"Kernel overlap — {path}"))
-            continue
         if not (isinstance(results, list) and results
                 and "mesh" in results[0]):
             # some other bench's artifact (out-of-core, prefetch,
@@ -121,7 +71,7 @@ def main():
             # unexpected shape
             keys = (sorted(results)[:8] if isinstance(results, dict)
                     else [type(results).__name__])
-            parts.append(f"### {path}\n\n_Skipped: no roofline/overlap "
+            parts.append(f"### {path}\n\n_Skipped: no roofline "
                          f"tables in this artifact (top-level: "
                          f"{', '.join(map(str, keys))})._\n")
             continue

@@ -15,10 +15,9 @@ gather assembling the layer-0 input.  Weights are random.
 
 Checks: every loss is finite; accel trainers' gradients come from
 distinct TPUs and the CPU trainer's (when it has a share) from the host
-CPU; the trainer picks XLA's gather for the combine and the Pallas
-cache-update kernel; on one real batch the Pallas combine, forced and
-compiled, equals ``kernels/ref.py``'s ``assemble_features`` exactly;
-``health()`` is ok;
+CPU; the trainer picks the Pallas cache-update kernel; on one real batch
+the combine (XLA's gather) on accel0 equals the frontier's feature rows
+exactly; ``health()`` is ok;
 with ``--chips 4`` the sharded and replicated planes give the same
 losses.  The last line of the output is one JSON object naming the device.
 Without a TPU the script exits non-zero and prints no result.
@@ -116,16 +115,14 @@ def check_grad_devices(hist, accel_platform: str, tag: str) -> None:
           f"{tag}: no accelerator trainer ever ran")
 
 
-def check_combine(tr, gnn, seed: int, accel_platform: str) -> None:
-    """One real batch through the trainer's loader, assembled by the
-    Pallas combine on accel0 and by the jnp reference; they must agree
-    bit for bit (the kernel is a one-hot f32 matmul: exact for finite
-    rows)."""
+def check_combine(tr, gnn, seed: int) -> None:
+    """One real batch through the trainer's loader and the combine the
+    trainer runs (XLA's gather and select) on accel0; the assembled
+    layer-0 block must equal the frontier's feature rows bit for bit."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from repro.graph import NumpySampler
-    from repro.kernels import ops, ref
+    from repro.kernels import ops
 
     ds = tr.dataset
     rng = np.random.default_rng(seed + 99)
@@ -138,30 +135,17 @@ def check_combine(tr, gnn, seed: int, accel_platform: str) -> None:
     dev = tr.accel_devices[0]
     cache = tr.cache.data_on(dev, version=look.version)
     miss = jax.device_put(block.rows, dev)
-    depth = tr.cfg.kernel_pipeline_depth
-
-    def combine(c, m):
-        return ops.assemble_features(c, m, look.slots, look.miss_index,
-                                     use_pallas=True, pipeline_depth=depth)
-
-    lowered = jax.jit(combine).lower(cache, miss).as_text()
-    got = np.asarray(combine(cache, miss))
-    want = np.asarray(jax.jit(ref.assemble_features)(
-        cache, miss, jax.device_put(jnp.asarray(look.slots), dev),
-        jax.device_put(jnp.asarray(look.miss_index), dev)))
+    slots, miss_index = jax.device_put((look.slots, look.miss_index), dev)
+    got = np.asarray(ops.assemble_features(cache, miss, slots, miss_index))
     tr.cache.release_lookup(look)
-    diff = float(np.max(np.abs(got - want))) if got.size else 0.0
+    frontier = np.asarray(mb.frontier(len(mb.fanouts)))
+    want = np.asarray(ds.take_features(frontier)).astype(got.dtype)
+    same = got.shape == want.shape and np.array_equal(got, want)
     hits = int((look.slots >= 0).sum())
     print(f"combine check: {got.shape[0]} positions ({hits} cache hits, "
           f"{block.rows.shape[0]} shipped unique rows) on {dev}, "
-          f"Mosaic kernel in program: {'tpu_custom_call' in lowered}, "
-          f"bit-identical to ref: {np.array_equal(got, want)}, "
-          f"max |diff| {diff!r}", flush=True)
-    if accel_platform == "tpu":
-        check("tpu_custom_call" in lowered,
-              "the combine was not lowered to the Mosaic kernel")
-    check(got.shape == want.shape and np.array_equal(got, want),
-          f"Pallas combine differs from the reference (max |diff| {diff})")
+          f"equal to the frontier's feature rows: {same}", flush=True)
+    check(same, "the combine differs from the frontier's feature rows")
 
 
 def one_chip_phase(ds, gnn, batch: int, steps: int, seed: int,
@@ -173,13 +157,10 @@ def one_chip_phase(ds, gnn, batch: int, steps: int, seed: int,
         tfp_depth=2, seed=seed))
     print(f"setup: trainer built in {time.perf_counter() - t0:.3f} s; "
           f"cpu trainer on {tr.cpu_device}, accel0 on {tr.accel_devices[0]}"
-          f", peaks of {tr.accel_platform}, Pallas combine "
-          f"{tr._assemble_pallas}, design-time shares "
+          f", peaks of {tr.accel_platform}, design-time shares "
           f"{tr.runtime.quantized_shares()}", flush=True)
     try:
         if accel_platform == "tpu":
-            check(not tr._assemble_pallas,
-                  "the trainer picked the Pallas combine, not XLA's gather")
             check(tr.cache.use_pallas_update,
                   "the trainer picked the jnp cache update, not Pallas")
         hist = train_steps(tr, steps, clock, "1-chip")
@@ -187,7 +168,7 @@ def one_chip_phase(ds, gnn, batch: int, steps: int, seed: int,
         if not any("cpu" in m.grad_devices for m in hist):
             print("note: the CPU trainer got no share in any step",
                   flush=True)
-        check_combine(tr, gnn, seed, accel_platform)
+        check_combine(tr, gnn, seed)
         health = tr.health()
         print(f"health: {health['status']} {health['events']}", flush=True)
         check(health["status"] == "ok", f"health: {health}")

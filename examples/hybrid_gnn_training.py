@@ -94,15 +94,8 @@ def main():
                          "strips already-warm rows from new submits, "
                          "cutting background read volume by the "
                          "cross-batch duplication factor (0 = off)")
-    ap.add_argument("--cache-assemble", default="auto",
-                    choices=["auto", "jnp", "pallas"],
-                    help="device-side cache+miss combine path: 'auto' "
-                         "and 'jnp' take XLA's gather ('auto' keeps the "
-                         "Pallas cache-update kernel on TPUs); 'pallas' "
-                         "forces the tiled combine kernel, interpreted "
-                         "off-TPU, e.g. with a pipeline depth")
     ap.add_argument("--kernel-pipeline-depth", type=int, default=1,
-                    help="Pallas combine/scatter DMA pipeline depth: 1 = "
+                    help="Pallas cache-update DMA pipeline depth: 1 = "
                          "single-buffered, 2-4 = multi-buffered "
                          "DMA/compute overlap (output stays "
                          "bit-identical at every depth)")
@@ -177,7 +170,6 @@ def main():
                         cache_refresh_frac=args.cache_refresh_frac,
                         cache_refresh_decay=args.cache_refresh_decay,
                         cache_drift_threshold=args.cache_drift_threshold,
-                        cache_assemble=args.cache_assemble,
                         async_refresh=args.async_refresh,
                         prefetch_windows=args.prefetch_windows,
                         prefetch_dedup_history=args.prefetch_dedup_history,

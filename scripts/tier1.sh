@@ -14,13 +14,6 @@
 #    prefetch on must show strictly lower load-stage stall, page-cache
 #    residency stays under the window-LRU bound, and trainer losses are
 #    bit-identical across the {prefetch, async_refresh} 4-config matrix,
-#  * kernel overlap (~60 s): pipelined (multi-buffered DMA) combine and
-#    scatter-update kernels at depths 2/4 bit-identical to the
-#    single-buffered depth-1 path and the jnp oracles (f32 + bf16,
-#    aliased slots), VMEM scratch within budget, no-worse wall time on
-#    interpret-mode CPU, and e2e trainer losses bit-identical across
-#    pipeline depths.
-#
 #  * sharded plane (~60 s): sharded vs replicated feature cache at equal
 #    per-device capacity — the union gather must ship strictly fewer
 #    host->device bytes than per-trainer dedup at n_accel >= 2, the
@@ -62,7 +55,6 @@ python -m benchmarks.fig_cache_ablation --smoke
 python -m benchmarks.fig_cache_ablation --smoke-refresh
 python -m benchmarks.bench_outofcore --smoke
 python -m benchmarks.bench_outofcore --smoke-prefetch
-python -m benchmarks.bench_kernel_overlap --smoke
 python -m benchmarks.bench_shard --smoke
 python -m benchmarks.bench_autotune --smoke
 echo "tier1: OK"

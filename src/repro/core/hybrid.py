@@ -18,9 +18,9 @@ Ablation knobs reproduce Fig. 11 exactly:
   * ``cache_fraction>0``                   -> "+cache": top-K hot node
     features pinned per accelerator (graph/featcache.py); the load stage
     gathers only cache misses, the transfer stage ships them, and the
-    on-device combine (kernels cache_combine / its jnp ref) assembles the
-    dense layer-0 input.  The perf model's Eq. 7/8 carry the matching
-    (1 - hit_rate) traffic term, so the initial task mapping already
+    on-device combine (``kernels.ops.assemble_features``, XLA's gather)
+    assembles the dense layer-0 input.  The perf model's Eq. 7/8 carry the
+    matching (1 - hit_rate) traffic term, so the initial task mapping already
     leans on the cheaper transfer; the DRM then refines from measured
     times as usual.
   * ``dedup=True`` (default)               -> the unit of the whole
@@ -193,12 +193,7 @@ class HybridConfig:
                                       #   stay addressable on the device and
                                       #   are not re-shipped (0 = off;
                                       #   replicated/dedup path only)
-    cache_assemble: str = "auto"      # "auto" | "jnp" | "pallas": "pallas"
-                                      #   forces the tiled combine kernel,
-                                      #   the others take XLA's gather;
-                                      #   "auto" keeps the Pallas cache
-                                      #   update on a TPU
-    kernel_pipeline_depth: int = 1    # Pallas combine/scatter DMA pipeline
+    kernel_pipeline_depth: int = 1    # Pallas cache-update DMA pipeline
                                       #   depth: 1 = single-buffered, 2..4 =
                                       #   multi-buffered DMA/compute overlap
                                       #   (bit-identical output either way)
@@ -350,6 +345,8 @@ def _compiled_update(optimizer: Optimizer) -> Callable:
 # through PipelineItem payloads (queue happens-before).
 @guarded_by("_state_lock", "_failed", "_degraded", "_refresh_error")
 class HybridGNNTrainer:
+    _assemble_pallas = False  # bench/runners/gnn_hybrid.py prints it
+
     def __init__(self, dataset: GraphDataset, gnn_cfg: GNNConfig,
                  cfg: HybridConfig, fault_injector=None):
         self.dataset = dataset
@@ -458,17 +455,12 @@ class HybridGNNTrainer:
         self._refresh_thread: Optional[threading.Thread] = None
         self._refresh_error: Optional[BaseException] = None
         self._staged_feedback: Optional[Tuple[float, float]] = None
-        # the combine is XLA's gather and select unless "pallas" forces the
-        # tiled kernel: that kernel needs a host sort over every position
-        # per batch, which costs more than the kernel saves on a TPU
-        self._assemble_pallas = cfg.cache_assemble == "pallas"
         if self.cache is not None:
             if fault_injector is not None:
                 self.cache.fault_injector = fault_injector
-            self.cache.use_pallas_update = (
-                self._assemble_pallas
-                or (cfg.cache_assemble == "auto"
-                    and any(d.platform == "tpu" for d in self.accel_devices)))
+            # the cache update takes the Pallas kernel where it runs on a TPU
+            self.cache.use_pallas_update = any(
+                d.platform == "tpu" for d in self.accel_devices)
             self.cache.kernel_pipeline_depth = cfg.kernel_pipeline_depth
             # hotness tracking costs two scattered adds per lookup and a
             # 4 B/node estimate array: only pay it when the refresh policy
@@ -826,16 +818,11 @@ class HybridGNNTrainer:
             # releasing the pin here lets a fully-drained old version
             # retire its [K, F] snapshots immediately
             self.cache.release_lookup(look)
-        slots, miss_index = look.slots, look.miss_index
-        if not self._assemble_pallas:
-            # XLA's gather indexes the tables on the device; the Pallas
-            # path keeps them host numpy to derive its DMA schedule first
-            with span("hyscale.transfer.ship"):
-                slots, miss_index = jax.device_put((slots, miss_index), dev)
-        return assemble_features(cache_data, miss, slots, miss_index,
-                                 use_pallas=self._assemble_pallas,
-                                 pipeline_depth=self.cfg
-                                 .kernel_pipeline_depth)
+        # XLA's gather indexes the tables on the device
+        with span("hyscale.transfer.ship"):
+            slots, miss_index = jax.device_put(
+                (look.slots, look.miss_index), dev)
+        return assemble_features(cache_data, miss, slots, miss_index)
 
     def _assemble_sharded(self, block: ShardMissBlock, dev) -> jax.Array:
         """Sharded-plane combine: the dense layer-0 input is assembled
@@ -865,13 +852,9 @@ class HybridGNNTrainer:
             sl.peer_requests,
             lambda p, v: self.cache.shards[p].data_on(
                 self._accel_device(f"accel{p}"), version=v),
-            dev, use_pallas=self._assemble_pallas,
-            pipeline_depth=self.cfg.kernel_pipeline_depth)
+            dev)
         x = assemble_features_sharded(local, peers + [miss], look.slots,
-                                      look.miss_index,
-                                      use_pallas=self._assemble_pallas,
-                                      pipeline_depth=self.cfg
-                                      .kernel_pipeline_depth)
+                                      look.miss_index)
         # combine + peer gathers hold their own block references: release
         # every shard pin so drained versions retire eagerly
         self.cache.release_union(sl)
@@ -1392,6 +1375,10 @@ class HybridGNNTrainer:
             grads, ttimes, metrics = self._run_trainers(item)
         with span("hyscale.update", iteration=i) as up:
             self._apply_update(grads)
+            # release the gradient buffers here, not when the frame ends:
+            # under a profiler that takes milliseconds, which would fall
+            # outside every loop span of the step
+            del grads
         with span("hyscale.drm", iteration=i):
             times = StageTimes(
                 t_sa=p["t"].get("t_sa", 0.0), t_sc=p["t"].get("t_sc", 0.0),

@@ -48,26 +48,19 @@ def ring_order(n: int, me: int) -> List[int]:
     return [(me + s) % n for s in range(1, n)]
 
 
-def peer_gather_rows(block: jax.Array, slots, dest_device,
-                     use_pallas: bool = False,
-                     pipeline_depth: int = 1) -> jax.Array:
+def peer_gather_rows(block: jax.Array, slots, dest_device) -> jax.Array:
     """Serve one peer request: gather ``slots`` rows out of the owner
-    shard's device-resident ``block`` (on the owner's device — the
-    Pallas path reuses the tiled combine machinery via
-    ``kernels.ops.gather_rows``), then ship only those rows to
+    shard's device-resident ``block`` (on the owner's device, one XLA
+    take via ``kernels.ops.gather_rows``), then ship only those rows to
     ``dest_device`` in one hop (the ICI transfer; on the CPU test mesh
     the hop is a same-backend ``device_put``)."""
     from repro.kernels.ops import gather_rows
-    rows = gather_rows(block, slots, use_pallas=use_pallas,
-                       pipeline_depth=pipeline_depth)
-    return jax.device_put(rows, dest_device)
+    return jax.device_put(gather_rows(block, slots), dest_device)
 
 
 def exchange_peer_rows(requests: Sequence[Tuple[int, Any, int]],
                        block_of: Callable[[int, int], jax.Array],
-                       dest_device,
-                       use_pallas: bool = False,
-                       pipeline_depth: int = 1) -> List[jax.Array]:
+                       dest_device) -> List[jax.Array]:
     """Pull the requested rows from each peer shard, in the ring order
     the requests were built in.
 
@@ -79,13 +72,9 @@ def exchange_peer_rows(requests: Sequence[Tuple[int, Any, int]],
     row-block per request, in request order: exactly the leading
     segments of the combined transfer source the union lookup's
     ``miss_index`` addresses."""
-    out: List[jax.Array] = []
-    for peer, slots, version in requests:
-        block = block_of(int(peer), int(version))
-        out.append(peer_gather_rows(block, slots, dest_device,
-                                    use_pallas=use_pallas,
-                                    pipeline_depth=pipeline_depth))
-    return out
+    return [peer_gather_rows(block_of(int(peer), int(version)), slots,
+                             dest_device)
+            for peer, slots, version in requests]
 
 
 def hierarchical_psum_mean(tree: Any) -> Any:

@@ -69,7 +69,7 @@ Components:
 
 The loader (``featload.FeatureLoader``) gathers only ``miss_ids`` on the
 host; the transfer stage ships the unique misses and a combine step
-(Pallas tiled ``cache_combine`` kernel or its jnp reference) expands them
+(``kernels.ops.assemble_features``, XLA's gather and select) expands them
 back into the dense positional layer-0 input on device — the duplication
 happens after the interconnect, for free.
 """

@@ -3,6 +3,8 @@
 Handles padding to MXU-aligned tile multiples, 2-D reshaping of vector
 operands (TPU lanes want >=2-D), and dispatch between the Pallas path and
 the pure-jnp reference (``use_pallas=False`` or non-TPU-friendly shapes).
+The layer-0 feature combine (``assemble_features``) has no Pallas kernel:
+it is XLA's gather and select on every platform.
 
 Each kernel call is compiled to Mosaic where the enclosing computation is
 lowered for a TPU and runs in ``interpret=True`` mode (the kernel body
@@ -23,9 +25,7 @@ from repro.core.spans import span
 
 from . import ref
 from .flash_attention import flash_attention_call
-from .gather_scatter_mm import (cache_combine_pipelined_kernel_call,
-                                cache_combine_tiled_kernel_call,
-                                cache_update_kernel_call,
+from .gather_scatter_mm import (cache_update_kernel_call,
                                 cache_update_pipelined_kernel_call,
                                 fused_update_kernel_call,
                                 segment_sum_kernel_call, sublane_rows)
@@ -69,8 +69,7 @@ def _pick_tile(dim: int, pref: int = 128, floor: int = 8) -> int:
 
 
 def assemble_features(cache: Optional[jax.Array], miss: jax.Array,
-                      slots, miss_index, use_pallas: bool = False,
-                      pipeline_depth: int = 1) -> jax.Array:
+                      slots, miss_index) -> jax.Array:
     """Assemble the dense positional layer-0 feature block from the
     device-resident hot cache + the transferred unique-miss rows (see
     graph/featcache.py).  Under frontier dedup the index tables point many
@@ -81,57 +80,26 @@ def assemble_features(cache: Optional[jax.Array], miss: jax.Array,
     the miss block).
 
     ``slots``/``miss_index`` are host numpy (the cache lookup makes them
-    on the host) or device arrays.  The default jnp path (XLA gather +
-    select) indexes them on the device: the caller puts them on the
-    destination device, and the jitted program specialises only on the
+    on the host) or device arrays.  The combine is one jitted XLA gather
+    and select that indexes them on the device: the caller puts them on
+    the destination device, and the program specialises only on the
     cache shape, the miss block's rows (the caller's bucket) and the
-    position count.  It is the combine on every platform: on a TPU v5e
-    host the Pallas path's per-batch host schedule costs more than its
-    kernel saves.  The call into the jitted program is the span
+    position count.  The call into it is the span
     ``hyscale.transfer.dispatch``.
 
     No VJP needed: layer-0 inputs are data, not parameters, so this sits
     outside the autodiff region of the train step.
-
-    ``use_pallas`` dispatches to the multi-row tiled combine kernel; it
-    derives its DMA schedule from host-numpy tables before anything
-    touches the device (``_assemble_tiled``).  Both paths are
-    bit-identical.
-
-    ``pipeline_depth`` (Pallas path only) selects how many tile windows
-    the combine kernel keeps in flight: 1 = the single-buffered
-    BlockSpec-driven kernel (DMAs serialized before each tile's compute),
-    2-4 = the multi-buffered kernel that overlaps tile i+1's window copy
-    with tile i's MXU expansion.  All depths are bit-identical.
     """
-    if not use_pallas:
-        slots, miss_index = jnp.asarray(slots), jnp.asarray(miss_index)
-        with span("hyscale.transfer.dispatch"):
-            return _assemble_ref(cache, miss, slots, miss_index)
-    return _assemble_tiled(cache, miss, np.asarray(slots),
-                           np.asarray(miss_index),
-                           depth=int(pipeline_depth))
+    slots, miss_index = jnp.asarray(slots), jnp.asarray(miss_index)
+    with span("hyscale.transfer.dispatch"):
+        return _assemble_ref(cache, miss, slots, miss_index)
 
 
-def gather_rows(block: jax.Array, slots, use_pallas: bool = False,
-                pipeline_depth: int = 1) -> jax.Array:
+def gather_rows(block: jax.Array, slots) -> jax.Array:
     """Gather ``slots`` rows out of a device-resident [K, F] block —
     the peer-serve half of the sharded plane's row exchange (the owner
-    shard reads the requested rows before the ICI hop).
-
-    The jnp path is one XLA take.  ``use_pallas`` reuses the tiled
-    combine machinery as a pure gather: every requested row is a "cache
-    hit" of the block, the miss source is empty, so the sort-by-rank
-    schedule, 4W VMEM window and multi-buffered DMA pipeline all apply
-    unchanged (bit-identical across paths and depths).
-    """
-    slots = np.asarray(slots, dtype=np.int32)
-    if not use_pallas or slots.shape[0] == 0:
-        return _gather_ref(block, jnp.asarray(slots))
-    miss_index = np.zeros(slots.shape[0], dtype=np.int32)
-    return _assemble_tiled(block,
-                           jnp.zeros((1, block.shape[1]), block.dtype),
-                           slots, miss_index, depth=int(pipeline_depth))
+    shard reads the requested rows before the ICI hop).  One XLA take."""
+    return _gather_ref(block, jnp.asarray(slots, dtype=jnp.int32))
 
 
 @jax.jit
@@ -140,15 +108,14 @@ def _gather_ref(block: jax.Array, slots: jax.Array) -> jax.Array:
 
 
 def assemble_features_sharded(cache: Optional[jax.Array], sources,
-                              slots, miss_index, use_pallas: bool = False,
-                              pipeline_depth: int = 1) -> jax.Array:
+                              slots, miss_index) -> jax.Array:
     """Shard-aware assemble: like ``assemble_features`` but the miss
     source arrives as an ordered list of device-resident row blocks —
     the peer-fetched segments (ring order) followed by the fresh
     host-shipped rows.  They are concatenated on device into the one
     combined source the union lookup's ``miss_index`` addresses, then
-    dispatched through the same combine machinery; ``cache`` is the
-    trainer's LOCAL shard block."""
+    combined by ``assemble_features``; ``cache`` is the trainer's LOCAL
+    shard block."""
     sources = [s for s in sources if int(s.shape[0])]
     if not sources:
         miss = None
@@ -160,9 +127,7 @@ def assemble_features_sharded(cache: Optional[jax.Array], sources,
         f = cache.shape[1] if cache is not None else 1
         dtype = cache.dtype if cache is not None else jnp.float32
         miss = jnp.zeros((1, f), dtype)
-    return assemble_features(cache, miss, slots, miss_index,
-                             use_pallas=use_pallas,
-                             pipeline_depth=pipeline_depth)
+    return assemble_features(cache, miss, slots, miss_index)
 
 
 @jax.jit
@@ -174,104 +139,6 @@ def _assemble_ref(cache: Optional[jax.Array], miss: jax.Array,
         # keep the gather well-defined when every row hits the cache
         miss = jnp.zeros((1, cache.shape[1]), cache.dtype)
     return ref.assemble_features(cache, miss, slots, miss_index)
-
-
-def _assemble_tiled(cache: Optional[jax.Array], miss: jax.Array,
-                    slots: np.ndarray, miss_index: np.ndarray,
-                    depth: int = 1) -> jax.Array:
-    """Host-side sort-by-source-row schedule for the tiled combine kernel.
-
-    The positional gather is recast as a *dense-rank expansion*: the
-    distinct cache slots the batch references are compacted to ranks
-    [0, H) and the distinct referenced miss rows to ranks [Hp, Hp+M) (two
-    device-local ``take``s of unique rows — U-scale work, not N-scale).
-    Every rank below the bounded pad gaps is referenced by >= 1 position, so
-    after sorting positions by rank each T_N output tile reads a monotone
-    rank run whose whole span provably fits in four aligned W-row blocks
-    of the dense source — the scalar-prefetched per-tile ``base`` block
-    index steers those DMAs and ``local`` addresses rows inside the 4W
-    VMEM window.  The kernel writes sorted rows; one XLA take un-permutes
-    (each positional row is produced exactly once, a bandwidth-bound
-    copy).  The schedule tables are O(N log N) host numpy over every
-    position (two ``unique``, two ``searchsorted``, a stable ``argsort``),
-    built in the transfer stage for each batch: about 61 ms of an 85-ms
-    iteration on one TPU v5e at ogbn-products' size, which is why only
-    ``use_pallas`` reaches this path.  The tables are the span
-    ``hyscale.transfer.schedule``, the call into the device program the
-    span ``hyscale.transfer.dispatch``.
-    """
-    with span("hyscale.transfer.schedule"):
-        tables, w, t_f = _tiled_schedule(slots, miss_index,
-                                         int(miss.shape[1]))
-    with span("hyscale.transfer.dispatch"):
-        return _assemble_tiled_device(cache, miss, *tables, w=w, t_f=t_f,
-                                      depth=depth)
-
-
-def _tiled_schedule(slots: np.ndarray, miss_index: np.ndarray, f: int
-                    ) -> Tuple[Tuple[np.ndarray, ...], int, int]:
-    """The tiled combine's host tables ``(hit_table, miss_table, base,
-    local, inv)`` and its row and column tiles ``w``, ``t_f``."""
-    n = int(slots.shape[0])
-    hit = slots >= 0
-    w = _pick_tile(n, 128)
-    t_f = _pick_tile(f)
-    # dense ranks: distinct referenced cache rows first, then distinct
-    # referenced miss rows — density is *constructed* (not assumed of the
-    # caller), so every rank below the bounded pad gaps is referenced.
-    # Both compact blocks are bucketed to W multiples so jit recompiles
-    # stay bounded; each pad gap is unreferenced and <= W-1 rows.
-    distinct_hit = np.unique(slots[hit]).astype(np.int32)
-    h = int(distinct_hit.shape[0])
-    hp = _round_up(h, w)
-    hit_table = np.zeros(hp, np.int32)
-    hit_table[:h] = distinct_hit
-    distinct_miss = np.unique(miss_index[~hit]).astype(np.int32)
-    dm = int(distinct_miss.shape[0])
-    mp = _round_up(dm, w)
-    miss_table = np.zeros(mp, np.int32)
-    miss_table[:dm] = distinct_miss
-    rank = np.empty(n, np.int32)
-    rank[hit] = np.searchsorted(distinct_hit, slots[hit]).astype(np.int32)
-    rank[~hit] = hp + np.searchsorted(
-        distinct_miss, miss_index[~hit]).astype(np.int32)
-    order = np.argsort(rank, kind="stable")
-    n_pad = _round_up(n, w)
-    # pad sorted ranks by repeating the max: keeps the last tile monotone
-    srank = np.pad(rank[order], (0, n_pad - n), mode="edge")
-    tiles = srank.reshape(n_pad // w, w)
-    base = (tiles[:, 0] // w).astype(np.int32)   # rows sorted: min is first
-    local = (tiles - base[:, None] * w).astype(np.int32)
-    # the dense-rank construction guarantees every tile fits its window
-    assert local.max(initial=0) < 4 * w, "tiled combine window overflow"
-    inv = np.empty(n, np.int32)     # permutation inverse via O(N) scatter
-    inv[order] = np.arange(n, dtype=np.int32)
-    return (hit_table, miss_table, base, local, inv), w, t_f
-
-
-@functools.partial(jax.jit, static_argnames=("w", "t_f", "depth"))
-def _assemble_tiled_device(cache, miss, hit_table, miss_table, base,
-                           local, inv, w: int, t_f: int,
-                           depth: int = 1) -> jax.Array:
-    f = miss.shape[1]
-    if cache is None:
-        compact = jnp.zeros((0, f), miss.dtype)
-    else:
-        compact = jnp.take(cache, hit_table, axis=0)
-    src = jnp.concatenate([compact, jnp.take(miss, miss_table, axis=0)],
-                          axis=0)
-    # three spare blocks past the last referenced row so the kernel's
-    # base..base+3 window always exists, columns padded to the F tile
-    sp = _round_up(int(src.shape[0]), w) + 4 * w
-    fp = _round_up(f, t_f)
-    src = jnp.pad(src, ((0, sp - src.shape[0]), (0, fp - f)))
-    if depth > 1:
-        out = _on_device(cache_combine_pipelined_kernel_call, src, base,
-                         local, t_n=w, t_f=t_f, depth=depth)
-    else:
-        out = _on_device(cache_combine_tiled_kernel_call, src, base, local,
-                         t_n=w, t_f=t_f)
-    return jnp.take(out, inv, axis=0)[:, :f]
 
 
 def update_cache_rows(cache: jax.Array, rows, slots,

@@ -1,7 +1,7 @@
-"""Frontier-deduplication coverage: unique/inverse round trips, the tiled
-combine kernel, traffic-accounting invariants, dedup-vs-legacy loss bit
-identity, the perf-model duplication factor, and the measured-hit-rate
-feedback loop."""
+"""Frontier-deduplication coverage: unique/inverse round trips, the
+feature combine, the transfer stage's bucket padding, traffic-accounting
+invariants, dedup-vs-legacy loss bit identity, the perf-model duplication
+factor, and the measured-hit-rate feedback loop."""
 import hypothesis.strategies as st
 import jax.numpy as jnp
 import numpy as np
@@ -14,8 +14,8 @@ from repro.core.perfmodel import (PLATFORMS, initial_task_mapping, t_load,
 from repro.graph import (FeatureCache, FeatureLoader, GNNConfig,
                          HashedFeatures, NumpySampler, build_cache,
                          compact_lookup, make_dataset)
-from repro.kernels import ops, ref
-from repro.kernels.gather_scatter_mm import cache_combine_kernel_call
+from repro.graph.featload import MissBlock
+from repro.kernels import ops
 
 
 def _toy_cache(n=200, f=8, capacity=50, seed=0):
@@ -64,7 +64,16 @@ def test_cached_compact_lookup_round_trip(size, capacity, seed):
         else look.num_miss == 0
 
 
-# ------------------------------------------------- tiled kernel parity
+# ------------------------------------------------------ feature combine
+
+
+def _combined(cache, miss, slots, mi):
+    """The positional block the combine must build, in plain numpy."""
+    miss = np.asarray(miss)
+    if cache is None:
+        return miss[mi]
+    return np.where((slots >= 0)[:, None], np.asarray(cache)[slots],
+                    miss[mi])
 
 
 def _combine_case(k, m, n, f, hits):
@@ -98,22 +107,15 @@ def _combine_case(k, m, n, f, hits):
     pytest.param(24, 6, 700, 100, "mixed", id="many-to-one-mixed"),
 ])
 def test_tiled_combine_matches_ref_and_legacy_kernel(k, m, n, f, hits):
-    """The Pallas combine (interpreted), the jnp combine (XLA's gather and
-    select, the trainer's default) and the oracle agree bit for bit."""
+    """The combine (XLA's gather and select) equals the numpy
+    reconstruction bit for bit, from host or device index tables."""
     cache, miss, slots, mi = _combine_case(k, m, n, f, hits)
-    a = ref.assemble_features(cache, miss, jnp.asarray(slots),
-                              jnp.asarray(mi))
-    b = ops.assemble_features(cache, miss, jnp.asarray(slots),
-                              jnp.asarray(mi), use_pallas=True)
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+    want = _combined(cache, miss, slots, mi)
     x = ops.assemble_features(cache, miss, slots, mi)
-    assert np.array_equal(np.asarray(x), np.asarray(b))
-    # the retired one-row-per-grid-step kernel is the parity baseline
-    sel = (slots < 0).astype(np.int32)
-    row = np.where(slots < 0, mi, slots).astype(np.int32)
-    c = cache_combine_kernel_call(cache, miss, jnp.asarray(sel),
-                                  jnp.asarray(row), interpret=True)
-    assert np.array_equal(np.asarray(a), np.asarray(c))
+    assert np.array_equal(np.asarray(x), want)
+    y = ops.assemble_features(cache, miss, jnp.asarray(slots),
+                              jnp.asarray(mi))
+    assert np.array_equal(np.asarray(y), want)
 
 
 def test_tiled_combine_duplicated_rows_and_no_cache():
@@ -122,19 +124,17 @@ def test_tiled_combine_duplicated_rows_and_no_cache():
     rows = jnp.asarray(rng.normal(size=(6, 40)), jnp.float32)
     inverse = rng.integers(0, 6, size=333).astype(np.int32)
     slots = np.full(333, -1, np.int32)
-    out = ops.assemble_features(None, rows, jnp.asarray(slots),
-                                jnp.asarray(inverse), use_pallas=True)
-    assert np.array_equal(np.asarray(out),
-                          np.asarray(ref.expand_rows(rows, inverse)))
-    x = ops.assemble_features(None, rows, slots, inverse)
+    out = ops.assemble_features(None, rows, slots, inverse)
+    assert np.array_equal(np.asarray(out), np.asarray(rows)[inverse])
+    x = ops.assemble_features(None, rows, jnp.asarray(slots),
+                              jnp.asarray(inverse))
     assert np.array_equal(np.asarray(x), np.asarray(out))
 
 
 def test_jnp_combine_ignores_the_distinct_hit_count():
-    """The jnp combine's program is keyed by the cache shape, the miss
+    """The combine's program is keyed by the cache shape, the miss
     block's rows and the position count: two batches that differ only in
-    how many distinct cache rows they hit share it, where the Pallas path
-    buckets the distinct hits and builds a second program."""
+    how many distinct cache rows they hit share it."""
     k, m, n, f = 400, 128, 600, 16
     rng = np.random.default_rng(3)
     cache = jnp.asarray(rng.normal(size=(k, f)), jnp.float32)
@@ -149,13 +149,11 @@ def test_jnp_combine_ignores_the_distinct_hit_count():
         batches.append((slots, mi))
     ops.assemble_features(cache, miss, *batches[0])
     jnp_programs = ops._assemble_ref._cache_size()
-    tiled_programs = ops._assemble_tiled_device._cache_size()
     for slots, mi in batches:
         a = ops.assemble_features(cache, miss, slots, mi)
-        b = ops.assemble_features(cache, miss, slots, mi, use_pallas=True)
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(np.asarray(a),
+                              _combined(cache, miss, slots, mi))
     assert ops._assemble_ref._cache_size() == jnp_programs
-    assert ops._assemble_tiled_device._cache_size() == tiled_programs + 2
 
 
 def test_tiled_combine_bf16_bit_identical():
@@ -164,11 +162,10 @@ def test_tiled_combine_bf16_bit_identical():
     miss = jnp.asarray(rng.normal(size=(5, 20)), jnp.bfloat16)
     slots = rng.integers(-1, 33, size=90).astype(np.int32)
     mi = np.where(slots < 0, rng.integers(0, 5, size=90), 0).astype(np.int32)
-    a = ref.assemble_features(cache, miss, jnp.asarray(slots), jnp.asarray(mi))
-    b = ops.assemble_features(cache, miss, jnp.asarray(slots), jnp.asarray(mi),
-                              use_pallas=True)
-    assert a.dtype == b.dtype == jnp.bfloat16
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+    out = ops.assemble_features(cache, miss, slots, mi)
+    want = _combined(cache, miss, slots, mi)
+    assert out.dtype == want.dtype == jnp.bfloat16
+    assert np.array_equal(np.asarray(out), want)
 
 
 # -------------------------------------------- traffic-stats invariants
@@ -250,17 +247,42 @@ def test_dedup_loss_bit_identical_to_legacy(small_ds):
     assert _loss_list(legacy_uncached) == _loss_list(dedup_cached)
 
 
-def test_dedup_pallas_combine_loss_bit_identical(small_ds):
-    """The tiled kernel path must reproduce the jnp combine bitwise."""
+@pytest.mark.parametrize("m,positions", [(1, 5), (127, 400), (128, 300),
+                                         (129, 200)],
+                         ids=["1", "127", "128", "129"])
+def test_assemble_pads_to_the_bucket(small_ds, m, positions):
+    """The trainer's transfer stage pads ``m`` unique miss rows up to the
+    128-row bucket, clamped to the frontier's position count, charges the
+    padding rows to the shipped stats, and the combine still rebuilds
+    exactly the frontier's feature rows."""
     ds, g = small_ds
-    base = _run_trainer(ds, g, dedup=True, frac=0.2)
-    cfg = HybridConfig(total_batch=128, n_accel=2, hybrid=False,
-                       use_drm=False, tfp_depth=2, seed=0,
-                       cache_fraction=0.2, dedup=True,
-                       cache_assemble="pallas")
-    tr = HybridGNNTrainer(ds, g, cfg)
-    tr.train(4)
-    assert _loss_list(base) == _loss_list(tr)
+    tr = HybridGNNTrainer(ds, g, HybridConfig(
+        total_batch=128, n_accel=1, hybrid=False, use_drm=False,
+        cache_fraction=0.2, dedup=True, seed=0))
+    try:
+        rng = np.random.default_rng(m)
+        slot_of = tr.cache.slot_of
+        miss_ids = rng.choice(np.flatnonzero(slot_of < 0), m, replace=False)
+        hit_ids = np.flatnonzero(slot_of >= 0)
+        n_hit = (positions - m) // 2
+        frontier = rng.permutation(np.concatenate([
+            miss_ids, rng.choice(miss_ids, positions - m - n_hit),
+            rng.choice(hit_ids, n_hit)])).astype(np.int64)
+        look = tr.cache.lookup(frontier, dedup=True, record=False, pin=True)
+        assert look.num_miss == m and look.num_rows == positions
+        rows = ds.take_features(look.miss_ids).astype(np.float32)
+        before = tr.loader.snapshot_stats()
+        x = tr._assemble(MissBlock(rows=rows, lookup=look),
+                         tr.accel_devices[0])
+        after = tr.loader.snapshot_stats()
+        assert np.array_equal(np.asarray(x), ds.take_features(frontier))
+        bucket = min(-(-m // 128) * 128, positions)
+        row_bytes = ds.feat_dim * 4
+        assert after.padding_bytes - before.padding_bytes == \
+            (bucket - m) * row_bytes
+        assert after.rows - before.rows == bucket - m
+    finally:
+        tr.close()
 
 
 # ------------------------------------------------ loader / pool details

@@ -1,5 +1,5 @@
 """Data-layer coverage: FeatureSource backend parity, the device-resident
-hot-feature cache, the cache-combine kernel, and end-to-end loss
+hot-feature cache, the cache + miss combine, and end-to-end loss
 equivalence of cached vs uncached training."""
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +10,7 @@ from repro.graph import (DenseFeatures, FeatureCache, FeatureLoader,
                          GNNConfig, HashedFeatures, NumpySampler,
                          PartitionedFeatures, as_feature_source, build_cache,
                          make_dataset)
-from repro.kernels import ops, ref
+from repro.kernels import ops
 
 
 def _rows(rng, n, size):
@@ -123,21 +123,21 @@ def test_cache_capacity_clamped_and_build_cache_off():
     assert build_cache(ds, 0.0) is None
 
 
-# ----------------------------------------------------- assemble / kernel
+# ------------------------------------------------------------ assemble
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_assemble_features_reconstructs_rows(use_pallas):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_assemble_features_reconstructs_rows(dtype):
     rng = np.random.default_rng(1)
     src, cache = _toy_cache(n=300, f=16, capacity=64)
     ids = _rows(rng, 300, 128)
     look = cache.lookup(ids)
-    miss = jnp.asarray(src.take(look.miss_ids))
+    miss = jnp.asarray(src.take(look.miss_ids).astype(dtype))
     out = ops.assemble_features(
-        jnp.asarray(src.take(cache.cached_ids)), miss,
-        jnp.asarray(look.slots), jnp.asarray(look.miss_index),
-        use_pallas=use_pallas)
-    assert np.array_equal(np.asarray(out), src.take(ids))
+        jnp.asarray(src.take(cache.cached_ids).astype(dtype)), miss,
+        jnp.asarray(look.slots), jnp.asarray(look.miss_index))
+    assert out.dtype == dtype
+    assert np.array_equal(np.asarray(out), src.take(ids).astype(dtype))
 
 
 def test_assemble_all_hits_empty_miss_block():
@@ -160,11 +160,11 @@ def test_ref_assemble_matches_kernel_fuzz():
         miss = jnp.asarray(rng.normal(size=(m, f)), jnp.float32)
         slots = rng.integers(-1, k, size=n).astype(np.int32)
         mi = np.where(slots < 0, rng.integers(0, m, size=n), 0).astype(np.int32)
-        a = ref.assemble_features(cache, miss, jnp.asarray(slots),
-                                  jnp.asarray(mi))
+        want = np.where((slots >= 0)[:, None], np.asarray(cache)[slots],
+                        np.asarray(miss)[mi])
         b = ops.assemble_features(cache, miss, jnp.asarray(slots),
-                                  jnp.asarray(mi), use_pallas=True)
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+                                  jnp.asarray(mi))
+        assert np.array_equal(np.asarray(b), want)
 
 
 # ------------------------------------------------------- loader + trainer

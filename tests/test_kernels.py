@@ -141,7 +141,7 @@ def test_cache_update_empty_is_identity(use_pallas):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-# ------------------------- pipelined (multi-buffered DMA) kernel parity
+# ---------------- feature combine; pipelined (multi-buffered DMA) update
 
 ASSEMBLE_CASES = [
     # (cache_rows, feat_dim, n_positions, n_miss)
@@ -158,7 +158,7 @@ def _assemble_case(k, f, n, m, dtype, seed=0):
     cache = jnp.asarray(rng.normal(size=(k, f)), jnp.float32).astype(dtype)
     miss = jnp.asarray(rng.normal(size=(m, f)), jnp.float32).astype(dtype)
     # slots drawn with replacement: many positions alias one cached row /
-    # one shipped miss row (the dedup fan-out the kernel exists for)
+    # one shipped miss row (the dedup fan-out the combine exists for)
     slots = rng.integers(-1, k, n).astype(np.int32)
     miss_index = rng.integers(0, m, n).astype(np.int32)
     return cache, miss, slots, miss_index
@@ -167,24 +167,15 @@ def _assemble_case(k, f, n, m, dtype, seed=0):
 @pytest.mark.parametrize("case", ASSEMBLE_CASES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_assemble_pipelined_matches_oracle_all_depths(case, dtype):
-    """The pipeline depth is a pure scheduling knob: every depth must
-    reproduce the jnp oracle AND the depth-1 kernel bit-for-bit (the
-    pipelined combine runs the same one-hot f32 matmul over the same
-    window values, just with the slab DMAs multi-buffered)."""
+    """The combine rebuilds every position from its cache slot or its
+    shipped row bit for bit, in the rows' own dtype."""
     k, f, n, m = case
     cache, miss, slots, miss_index = _assemble_case(k, f, n, m, dtype)
-    want = np.asarray(ref.assemble_features(
-        cache, miss, jnp.asarray(slots), jnp.asarray(miss_index)
-        ).astype(jnp.float32))
-    d1 = None
-    for depth in PIPELINE_DEPTHS:
-        got = np.asarray(ops.assemble_features(
-            cache, miss, slots, miss_index, use_pallas=True,
-            pipeline_depth=depth).astype(jnp.float32))
-        np.testing.assert_array_equal(got, want, err_msg=f"depth={depth}")
-        if d1 is None:
-            d1 = got
-        np.testing.assert_array_equal(got, d1, err_msg=f"depth={depth}")
+    got = ops.assemble_features(cache, miss, slots, miss_index)
+    want = np.where((slots >= 0)[:, None], np.asarray(cache)[slots],
+                    np.asarray(miss)[miss_index])
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @pytest.mark.parametrize("case", UPDATE_CASES)
@@ -226,11 +217,6 @@ def test_cache_update_pipelined_all_aliased_one_slot(depth):
 
 def test_pipelined_kernels_reject_bad_depth():
     from repro.kernels import gather_scatter_mm as gsm
-    src = jnp.zeros((512, 128), jnp.float32)
-    base = np.zeros(1, np.int32)
-    local = np.zeros((1, 128), np.int32)
-    with pytest.raises(ValueError, match="depth must be >= 1"):
-        gsm.cache_combine_pipelined_kernel_call(src, base, local, depth=0)
     cache = jnp.zeros((8, 128), jnp.float32)
     rows = jnp.zeros((8, 128), jnp.float32)
     mask = jnp.ones((8, 1), jnp.int32)
@@ -241,24 +227,24 @@ def test_pipelined_kernels_reject_bad_depth():
 
 
 def test_vmem_scratch_budget():
-    """The depth-4 target window (128x128 f32 tiles, 4W-row slabs) must
-    fit the VMEM scratch budget; an over-budget request raises with the
-    knobs to turn, and the kernel entry point enforces it."""
+    """The update kernel's depth-4 blocks (8-row f32 blocks of a 128-lane
+    tile) fit the VMEM scratch budget; an over-budget request raises with
+    the knobs to turn, and the kernel entry point enforces it."""
     from repro.kernels import gather_scatter_mm as gsm
-    # target window at depth 4: 4 slabs x (4*128 rows x 128 cols) x 4 B
-    target = 4 * 4 * 128 * 128 * 4
+    # depth 4: 4 slots x (8 rows x 128 cols) x 4 B
+    target = 4 * gsm.sublane_rows(jnp.float32) * 128 * 4
     assert target <= gsm.VMEM_SCRATCH_BUDGET_BYTES
-    gsm.check_vmem_scratch(target, "combine depth=4")    # must not raise
+    gsm.check_vmem_scratch(target, "update depth=4")     # must not raise
     with pytest.raises(ValueError, match="exceeds the"):
         gsm.check_vmem_scratch(gsm.VMEM_SCRATCH_BUDGET_BYTES + 1, "probe")
-    # the combine entry point itself rejects an over-budget config:
-    # depth 33 x 4*128x128 f32 slabs = 8.25 MiB > 8 MiB
-    src = jnp.zeros((4 * 128 + 128, 128), jnp.float32)
-    base = np.zeros(1, np.int32)
-    local = np.zeros((1, 128), np.int32)
+    # the update entry point itself rejects an over-budget config:
+    # depth 33 x 8x8192 f32 blocks = 8.25 MiB > 8 MiB
+    cache = jnp.zeros((8, 8192), jnp.float32)
+    mask = jnp.ones((8, 1), jnp.int32)
+    blocks = jnp.zeros((1,), jnp.int32)
     with pytest.raises(ValueError, match="exceeds the"):
-        gsm.cache_combine_pipelined_kernel_call(src, base, local,
-                                                t_n=128, t_f=128, depth=33)
+        gsm.cache_update_pipelined_kernel_call(cache, cache, mask, blocks,
+                                               t_f=8192, depth=33)
 
 
 @pytest.mark.parametrize("shape", [(2, 32, 2, 2, 16), (1, 64, 1, 4, 32)])

@@ -3,8 +3,8 @@
 Placement invariants (disjoint shards, full coverage, for both hash and
 degree-range policies), union-lookup classification counts against a
 brute-force oracle, the union-gather's strict byte reduction and stats
-identity, the peer-exchange collective + shard-aware assemble (jnp and
-Pallas-interpret paths), sharded-vs-replicated loss bit-identity end to
+identity, the peer-exchange collective + shard-aware assemble (f32 and
+bf16 rows), sharded-vs-replicated loss bit-identity end to
 end (including a dynamic refresh mid-run), and the cross-iteration
 recent-rows LRU (skip / count / invalidate-on-refresh)."""
 import jax
@@ -22,8 +22,9 @@ from repro.kernels.ops import assemble_features_sharded, gather_rows
 N, F = 400, 12
 
 
-def _plane(n_shards=2, capacity=30, placement="hash", seed=0):
-    src = HashedFeatures(N, F, seed=seed)
+def _plane(n_shards=2, capacity=30, placement="hash", seed=0,
+           dtype=np.float32):
+    src = HashedFeatures(N, F, seed=seed, dtype=dtype)
     hotness = np.arange(N, 0, -1, dtype=np.float64)  # node 0 hottest
     return src, ShardedFeatureCache(src, hotness, capacity, n_shards,
                                     placement=placement)
@@ -209,11 +210,11 @@ def test_gather_rows_jnp_pallas_parity():
     rng = np.random.default_rng(5)
     block = jnp.asarray(rng.normal(size=(64, F)).astype(np.float32))
     slots = rng.integers(0, 64, 17).astype(np.int32)
-    ref = np.asarray(gather_rows(block, slots, use_pallas=False))
-    pal = np.asarray(gather_rows(block, slots, use_pallas=True,
-                                 pipeline_depth=2))
-    assert np.array_equal(ref, pal)
-    assert np.array_equal(ref, np.asarray(block)[slots])
+    want = np.asarray(block)[slots]
+    assert np.array_equal(np.asarray(gather_rows(block, slots)), want)
+    assert np.array_equal(
+        np.asarray(gather_rows(block, jnp.asarray(slots))), want)
+    assert gather_rows(block, slots[:0]).shape == (0, F)
 
 
 def test_exchange_peer_rows_preserves_request_order():
@@ -230,11 +231,11 @@ def test_exchange_peer_rows_preserves_request_order():
     assert np.array_equal(np.asarray(out[1]), np.asarray(blocks[2])[[5, 5]])
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_sharded_assemble_reconstructs_frontier(use_pallas):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sharded_assemble_reconstructs_frontier(dtype):
     """Local block + ring-ordered peer rows + fresh host rows must
     assemble into exactly the positional [frontier, F] source rows."""
-    src, plane = _plane(n_shards=3, capacity=35)
+    src, plane = _plane(n_shards=3, capacity=35, dtype=dtype)
     rng = np.random.default_rng(7)
     frontiers = {f"accel{i}": rng.integers(0, N, 120) for i in range(3)}
     ordinals = {f"accel{i}": i for i in range(3)}
@@ -246,12 +247,12 @@ def test_sharded_assemble_reconstructs_frontier(use_pallas):
         peers = exchange_peer_rows(
             sl.peer_requests,
             lambda p, v: plane.shards[p].data_on(dev, version=v),
-            dev, use_pallas=use_pallas)
-        fresh = jnp.asarray(src.take(look.miss_ids).astype(np.float32))
+            dev)
+        fresh = jnp.asarray(src.take(look.miss_ids))
         x = assemble_features_sharded(local, peers + [fresh], look.slots,
-                                      look.miss_index,
-                                      use_pallas=use_pallas)
-        want = src.take(frontiers[name]).astype(np.float32)
+                                      look.miss_index)
+        want = src.take(frontiers[name])
+        assert x.dtype == want.dtype == dtype
         assert np.array_equal(np.asarray(x), want)
         plane.release_union(sl)
 

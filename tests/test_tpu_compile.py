@@ -1,11 +1,13 @@
-"""The main path's Pallas kernels, compiled by the TPU compiler for a
-described (not attached) TPU v5e at the paper's real widths.
+"""The main path's Pallas kernels and its XLA feature combines, compiled
+by the TPU compiler for a described (not attached) TPU v5e at the paper's
+real widths.
 
 Interpret mode cannot catch what only Mosaic refuses: vector reads out of
 scalar memory, blocks and DMA slices off the (8, 128) tile, lane widths
-that are not multiples of 128 (f0 = 100 and 756).  Each test lowers one
-kernel call for one chip of a ``v5e:2x2`` topology and checks the
-compiled program holds the Mosaic kernel.  Nothing runs.
+that are not multiples of 128 (f0 = 100 and 756).  Each kernel test
+lowers one kernel call for one chip of a ``v5e:2x2`` topology and checks
+the compiled program holds the Mosaic kernel; each combine test checks
+that its program holds none and fits one chip.  Nothing runs.
 
 Shapes follow ``sage-products`` at batch 1024 with half the batch on one
 accelerator (fanouts (25, 10)): 146,432 layer-0 positions, a 20% hot
@@ -15,7 +17,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -27,7 +28,6 @@ SHARE = 512                          # targets on one accelerator
 FANOUTS = (25, 10)
 POSITIONS = SHARE * (1 + FANOUTS[0]) * (1 + FANOUTS[1])
 CACHE_ROWS = 489_806
-T_N = 128
 
 
 @pytest.fixture(scope="module")
@@ -52,22 +52,17 @@ def _compile(fn, sharding, *shapes):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("f0", WIDTHS)
-def test_combine_tiled_compiles(one_chip, f0):
-    g = POSITIONS // T_N
-    _compile(lambda s, b, l: gsm.cache_combine_tiled_kernel_call(
-        s, b, l, t_n=T_N, interpret=False), one_chip,
-        ((POSITIONS + 4 * T_N, _padded(f0)), jnp.float32),
-        ((g,), jnp.int32), ((g, T_N), jnp.int32))
-
-
-@pytest.mark.parametrize("f0", WIDTHS)
-def test_combine_pipelined_depth2_compiles(one_chip, f0):
-    g = POSITIONS // T_N
-    _compile(lambda s, b, l: gsm.cache_combine_pipelined_kernel_call(
-        s, b, l, t_n=T_N, depth=2, interpret=False), one_chip,
-        ((POSITIONS + 4 * T_N, _padded(f0)), jnp.float32),
-        ((g,), jnp.int32), ((g, T_N), jnp.int32))
+def _compile_xla(fn, sharding, *shapes):
+    """Compile ``fn`` for one chip: no Mosaic kernel, and its arguments,
+    output and temporaries fit the chip's 16 GiB."""
+    args = [None if s is None else
+            jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16 * 2**30
 
 
 def _update_shapes(f0, dtype=jnp.float32, blocks=64):
@@ -146,39 +141,50 @@ def test_model_entry_points_pick_the_compiled_kernel(one_chip, f0):
              ((f0, 256), jnp.float32))
 
 
-@pytest.mark.parametrize("f0", [100, 756])
-@pytest.mark.parametrize("depth", [1, 2])
-def test_trainer_combine_path_compiles(one_chip, f0, depth):
-    """The whole Pallas combine that ``cache_assemble="pallas"`` forces
-    (host schedule, compaction, kernel, un-permute) for a real batch
-    layout: 20% of positions served by the hot cache, the rest by
-    deduplicated shipped rows."""
-    rng = np.random.default_rng(0)
-    miss_rows = POSITIONS // 4
-    slots = np.where(rng.random(POSITIONS) < 0.2,
-                     rng.integers(0, CACHE_ROWS, POSITIONS), -1
-                     ).astype(np.int32)
-    miss_index = rng.integers(0, miss_rows, POSITIONS).astype(np.int32)
-    _compile(lambda c, m: ops.assemble_features(
-        c, m, slots, miss_index, use_pallas=True, pipeline_depth=depth),
-        one_chip, ((CACHE_ROWS, f0), jnp.float32),
-        ((miss_rows, f0), jnp.float32))
-
-
-@pytest.mark.parametrize("f0", [100, 756])
+@pytest.mark.parametrize("f0", WIDTHS)
 def test_xla_combine_compiles(one_chip, f0):
-    """The trainer's default combine, XLA's gather and select, at the same
-    real layout with the index tables on the device: no Mosaic kernel,
-    and it fits one chip."""
-    miss_rows = POSITIONS // 4
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
-        ((CACHE_ROWS, f0), jnp.float32), ((miss_rows, f0), jnp.float32),
-        ((POSITIONS,), jnp.int32), ((POSITIONS,), jnp.int32))]
-    compiled = ops._assemble_ref.lower(*args).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes) < 16 * 2**30
+    """The trainer's combine, XLA's gather and select, for a real batch
+    layout with the index tables on the device: 20% of the hot cache's
+    rows and a quarter of the positions as shipped unique rows."""
+    _compile_xla(ops._assemble_ref, one_chip,
+                 ((CACHE_ROWS, f0), jnp.float32),
+                 ((POSITIONS // 4, f0), jnp.float32),
+                 ((POSITIONS,), jnp.int32), ((POSITIONS,), jnp.int32))
+
+
+@pytest.mark.parametrize("f0", WIDTHS)
+def test_xla_combine_without_cache_compiles(one_chip, f0):
+    """The cache-less combine of ``sage-products.no-cache``: every
+    position reads the shipped unique rows."""
+    _compile_xla(ops._assemble_ref, one_chip, (None, None),
+                 ((POSITIONS // 2, f0), jnp.float32),
+                 ((POSITIONS,), jnp.int32), ((POSITIONS,), jnp.int32))
+
+
+@pytest.mark.parametrize("f0", WIDTHS)
+def test_sharded_combine_compiles(one_chip, f0):
+    """The sharded plane's combine on one of four chips: its local shard
+    of the hot set, one segment of rows pulled from a peer shard and the
+    fresh host rows, concatenated on the device and combined."""
+    seg = POSITIONS // 8
+
+    def combine(local, peer, fresh, slots, miss_index):
+        return ops.assemble_features_sharded(local, [peer, fresh], slots,
+                                             miss_index)
+
+    _compile_xla(jax.jit(combine), one_chip,
+                 ((CACHE_ROWS // 4, f0), jnp.float32),
+                 ((seg, f0), jnp.float32), ((seg, f0), jnp.float32),
+                 ((POSITIONS,), jnp.int32), ((POSITIONS,), jnp.int32))
+
+
+@pytest.mark.parametrize("f0", WIDTHS)
+def test_peer_gather_compiles(one_chip, f0):
+    """The owner's half of a peer request: the requested rows gathered out
+    of its shard block before the hop."""
+    _compile_xla(jax.jit(ops.gather_rows), one_chip,
+                 ((CACHE_ROWS // 4, f0), jnp.float32),
+                 ((POSITIONS // 8,), jnp.int32))
 
 
 def test_gat_train_step_compiles(one_chip):

@@ -1,8 +1,8 @@
 """The trainer's stage spans on the profiler clock (``core.spans``).
 
 A tiny hybrid trainer (CPU trainer + one accelerator trainer, device
-cache; the Pallas combine in interpret mode, or XLA's gather) runs a few
-iterations under ``jax.profiler``; the ``.xplane.pb`` it writes is read back with
+cache) runs a few iterations under ``jax.profiler``, with the pipeline
+two or one batches ahead or sequential; the ``.xplane.pb`` it writes is read back with
 ``ProfileData``.  Each host thread has a line of its own on the host
 plane, so "its thread" is "its line"."""
 import collections
@@ -51,25 +51,23 @@ def _events(trace_dir):
     return out
 
 
-@pytest.fixture(scope="module",
-                params=[(2, "pallas"), (0, "pallas"), (2, "auto")],
-                ids=["tfp2", "sequential", "tfp2-xla-combine"])
+@pytest.fixture(scope="module", params=[2, 1, 0],
+                ids=["tfp2", "tfp1", "sequential"])
 def traced(request, tmp_path_factory):
-    depth, assemble = request.param
+    depth = request.param
     ds = make_dataset("ogbn-products", scale=0.002, seed=0)
     gnn = GNNConfig(model="sage", layer_dims=(100, 32, 47), fanouts=(4, 3),
                     num_classes=47)
     tr = HybridGNNTrainer(ds, gnn, HybridConfig(
         total_batch=128, n_accel=1, hybrid=True, use_drm=False,
-        tfp_depth=depth, cache_fraction=0.3, dedup=True,
-        cache_assemble=assemble, seed=0))
+        tfp_depth=depth, cache_fraction=0.3, dedup=True, seed=0))
     tr.train(3)                              # compile outside the trace
     trace_dir = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(trace_dir)
     hist = list(tr.train(ITERS)[-ITERS:])
     jax.profiler.stop_trace()
     tr.close()
-    return depth, hist, _events(trace_dir), assemble
+    return depth, hist, _events(trace_dir)
 
 
 def _of(events, name, iteration=None):
@@ -88,7 +86,7 @@ def _loop_line(events):
 
 
 def test_every_span_once_per_iteration_on_its_thread(traced):
-    depth, hist, events, _ = traced
+    depth, hist, events = traced
     assert [m.iteration for m in hist] == list(range(ITERS))
     loop = _loop_line(events)
     steps = _of(events, "hyscale.step")
@@ -115,12 +113,11 @@ def test_every_span_once_per_iteration_on_its_thread(traced):
 
 
 def test_child_spans_lie_inside_their_parent(traced):
-    depth, _, events, assemble = traced
-    # the XLA combine has no schedule: the span holds the miss rows'
-    # bucket padding, which a batch may not need
+    depth, _, events = traced
+    # the schedule span holds the miss rows' bucket padding, which a batch
+    # may not need
     every_batch = {part: layer for part, layer in PARTS.items()
-                   if assemble == "pallas"
-                   or part != "hyscale.transfer.schedule"}
+                   if part != "hyscale.transfer.schedule"}
     for i in range(ITERS):
         (st,) = [e for e in _of(events, "hyscale.step")
                  if e[4]["step_num"] == i]
@@ -146,7 +143,7 @@ def test_stage_times_are_their_spans(traced):
     the median iteration.  The two clocks are read one Python call apart,
     where another thread may take the GIL for up to a switch interval, so
     a single iteration may differ by that much."""
-    _, hist, events, _ = traced
+    _, hist, events = traced
 
     def secs(name, i, key="iteration"):
         (e,) = [e for e in _of(events, name) if e[4][key] == i]
@@ -171,14 +168,14 @@ def test_stage_times_are_their_spans(traced):
 def test_iter_time_is_the_wall_time(traced):
     """The iteration's wall time holds its training step and its update,
     one after the other (the modelled max of stage times need not)."""
-    _, hist, _, _ = traced
+    _, hist, _ = traced
     for m in hist:
         assert m.iter_time >= max(m.times.t_tc, m.times.t_ta) + m.t_sync
         assert m.mteps == pytest.approx(m.edges / m.iter_time / 1e6)
 
 
 def test_wait_batch_and_loop_spans_cover_the_steps(traced):
-    _, _, events, _ = traced
+    _, _, events = traced
     covered = collections.defaultdict(float)
     for name in LOOP[1:]:
         for e in _of(events, name):

@@ -120,37 +120,39 @@ def test_synchronizer_without_device_sums_in_place():
 
 
 class _ReadsTpu:
-    """A host device whose platform reads as a v5e's: the trainer's
-    routing sees a TPU, and whatever it places still lands on the host."""
-    platform = "tpu"
+    """A host device whose platform reads as a v5e's (or another
+    accelerator's): the trainer's routing sees that platform, and
+    whatever it places still lands on the host."""
     device_kind = "TPU v5 lite"
 
-    def __init__(self, dev):
+    def __init__(self, dev, platform="tpu"):
         self._dev = dev
+        self.platform = platform
 
     def __getattr__(self, name):
         return getattr(self._dev, name)
 
 
-@pytest.mark.parametrize("assemble,pallas_combine", [("auto", False),
-                                                     ("pallas", True)])
-def test_combine_routing_on_a_tpu(monkeypatch, assemble, pallas_combine):
-    """On a TPU, "auto" takes XLA's gather for the combine and keeps the
-    Pallas cache-update kernel; "pallas" forces both kernels."""
+@pytest.mark.parametrize("platform,pallas_update", [("tpu", True),
+                                                    ("gpu", False)])
+def test_combine_routing_on_a_tpu(monkeypatch, platform, pallas_update):
+    """The combine is XLA's gather everywhere; the cache update takes the
+    Pallas kernel on a TPU and the jnp scatter on another accelerator."""
     from repro.core import HybridConfig, HybridGNNTrainer, hybrid
     from repro.graph import GNNConfig, make_dataset
     monkeypatch.setattr(
         hybrid, "resolve_trainer_devices",
-        lambda n, cpus, accels: (cpus[0], [_ReadsTpu(cpus[0])] * n))
+        lambda n, cpus, accels: (cpus[0],
+                                 [_ReadsTpu(cpus[0], platform)] * n))
     ds = make_dataset("ogbn-products", scale=0.002, seed=0)
     g = GNNConfig(model="sage", layer_dims=(100, 32, 47), fanouts=(4, 3),
                   num_classes=47)
     tr = HybridGNNTrainer(ds, g, HybridConfig(
         total_batch=128, n_accel=1, hybrid=True, use_drm=False,
-        cache_fraction=0.2, cache_assemble=assemble, seed=0))
+        cache_fraction=0.2, seed=0))
     try:
-        assert tr.accel_devices[0].platform == "tpu"
-        assert tr._assemble_pallas is pallas_combine
-        assert tr.cache.use_pallas_update is True
+        assert tr.accel_devices[0].platform == platform
+        assert tr._assemble_pallas is False
+        assert tr.cache.use_pallas_update is pallas_update
     finally:
         tr.close()
