@@ -316,6 +316,16 @@ class _TrainerFailure(RuntimeError):
     pass
 
 
+def _reads_in_place(x: jax.Array, rows: np.ndarray) -> bool:
+    """Whether ``x``, just ``device_put`` from ``rows``, reads ``rows``'
+    memory in place: XLA's CPU client keeps a host array whose address is
+    64-byte aligned instead of copying it."""
+    if rows.size == 0 or next(iter(x.devices())).platform != "cpu":
+        return False
+    lo = rows.ctypes.data
+    return lo <= x.unsafe_buffer_pointer() < lo + rows.nbytes
+
+
 def _grad_fn(gnn_cfg: GNNConfig) -> Callable:
     def _grad(params, batch: MiniBatch, x0):
         (loss, acc), grads = jax.value_and_grad(
@@ -756,6 +766,14 @@ class HybridGNNTrainer:
             else:
                 p["features"][name] = self.loader.load(
                     mb, to_device=(name != "cpu"))
+        # the loader's buffers behind these rows go back to it once this
+        # batch's update has run (_train_step): a CPU trainer may read
+        # them in place.  A block the recent-rows LRU registered keeps its
+        # rows, since later batches re-read what they were shipped into.
+        p["host_rows"] = {
+            name: f.rows if isinstance(f, MissBlock) else f
+            for name, f in p["features"].items()
+            if not (isinstance(f, MissBlock) and f.shipped is not None)}
         # storage-I/O stall share of the load stage (cold mmap faults the
         # prefetcher did not hide) — DRM-visible via StageTimes
         p["t"]["t_load_stall"] = (self.loader.stats.stall_seconds
@@ -892,6 +910,10 @@ class HybridGNNTrainer:
             else:
                 with span("hyscale.transfer.ship"):
                     x = jax.device_put(feat, dev)
+                if _reads_in_place(x, feat):
+                    # the array, and whoever keeps it past this batch,
+                    # owns those rows now: the loader never refills them
+                    p["host_rows"].pop(name, None)
             with span("hyscale.transfer.ship"):
                 mb = jax.device_put(p["minibatch"][name], dev)
             p["features"][name] = x
@@ -1379,6 +1401,8 @@ class HybridGNNTrainer:
             # under a profiler that takes milliseconds, which would fall
             # outside every loop span of the step
             del grads
+            # the batch is read: its host feature rows can be refilled
+            self.loader.release(p.pop("host_rows", {}).values())
         with span("hyscale.drm", iteration=i):
             times = StageTimes(
                 t_sa=p["t"].get("t_sa", 0.0), t_sc=p["t"].get("t_sc", 0.0),

@@ -47,6 +47,18 @@ Two further levers stack on top of the compact path:
     whenever the cache version moves.  Savings in
     ``recent_saved_bytes``.
 
+Every gather writes into a host buffer the loader lends out: the smallest
+released one that holds the rows, else a new one whose row count is rounded
+up to ``_ROW_QUANTUM``.  The caller hands it back with ``release`` once
+nothing reads the rows any more (the hybrid trainer: after the update of the
+batch that read them, since a CPU trainer may read them in place).  A buffer
+that is never released is only freed with its last reference, so a missed
+release costs a fresh allocation, never a reuse.  A reused buffer's pages are
+already faulted in, and the pool threads fill their slices of it in place,
+with no concatenate: a full-size batch's frontier is 40-65 MB, above
+glibc's mmap threshold, so a fresh array per gather is a fresh mapping
+faulted page by page.  ``reused_gathers`` / ``fresh_gathers`` count them.
+
 Supports optional on-the-fly down-cast to bf16 ("data quantization to
 relieve the stress on the PCIe bandwidth" — the paper's §VIII future-work
 item) and reports rows/bytes statistics consumed by the DRM engine and the
@@ -64,6 +76,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -82,6 +95,9 @@ from .storage import GraphDataset
 __all__ = ["FeatureLoader", "LoadStats", "MissBlock", "ShardMissBlock"]
 
 _BF16 = jnp.bfloat16  # numpy-compatible via ml_dtypes under the hood
+# a gather buffer's rows are rounded up to a multiple of this, so that
+# sampling noise in a batch's row count still finds a released buffer
+_ROW_QUANTUM = 1024
 
 
 @dataclasses.dataclass
@@ -111,6 +127,8 @@ class LoadStats:
                              #   gather's pool threads, so it can exceed
                              #   the wall-clock `seconds`.  0 on
                              #   RAM-resident sources
+    reused_gathers: int = 0  # gathers filled into a released buffer
+    fresh_gathers: int = 0   # gathers that had to allocate their buffer
 
     @property
     def hit_rate(self) -> float:
@@ -138,6 +156,8 @@ class LoadStats:
         self.recent_rows += other.recent_rows
         self.recent_saved_bytes += other.recent_saved_bytes
         self.stall_seconds += other.stall_seconds
+        self.reused_gathers += other.reused_gathers
+        self.fresh_gathers += other.fresh_gathers
 
 
 @dataclasses.dataclass
@@ -193,7 +213,9 @@ class ShardMissBlock(MissBlock):
 # the load and transfer pipeline stages run in different threads and both
 # account into the same stats windows; every merge resolves its
 # destination and runs under _stats_lock
+# the load stage lends gather buffers, the train loop releases them
 @guarded_by("_stats_lock", "stats", "window", "host_stats")
+@guarded_by("_buf_lock", "_free", "_lent")
 class FeatureLoader:
     def __init__(self, dataset: GraphDataset, transfer_dtype: str = "float32",
                  num_threads: int = 1,
@@ -231,6 +253,11 @@ class FeatureLoader:
         # chunked gather saves on small frontiers)
         self._pool = None
         self._pool_size = 0
+        # gather buffers: released ones, and id -> weak reference of each
+        # one lent out (weak, so a buffer never released is still freed)
+        self._free: List[np.ndarray] = []
+        self._lent: Dict[int, weakref.ref] = {}
+        self._buf_lock = threading.Lock()
         self._row_bytes = wire_row_bytes(dataset.feat_dim, transfer_dtype)
 
     def _account(self, dest: str, delta: LoadStats) -> None:
@@ -272,6 +299,50 @@ class FeatureLoader:
             self._pool.shutdown(wait=False)
             self._pool = None
             self._pool_size = 0
+        # buffers still lent out are never taken back after this
+        with self._buf_lock:
+            self._free.clear()
+            self._lent.clear()
+
+    def _acquire(self, n: int) -> Tuple[np.ndarray, bool]:
+        """A buffer of at least ``n`` rows for a gather, and whether it was
+        a released one: the smallest released buffer that holds ``n``
+        rows, else a new one.  The new one takes the place of the
+        smallest released buffer (none holds ``n`` rows), so the loader
+        never keeps more buffers than were lent out at once."""
+        width, dtype = self.source.shape[1], np.dtype(self.source.dtype)
+        with self._buf_lock:
+            mine = [(b.shape[0], i) for i, b in enumerate(self._free)
+                    if b.shape[1] == width and b.dtype == dtype]
+            fits = [m for m in mine if m[0] >= n]
+            pick = min(fits or mine, default=None)
+            buf = self._free.pop(pick[1]) if pick is not None else None
+            if not fits:
+                buf = None
+                # forget buffers lent and never released (a failed batch)
+                self._lent = {k: r for k, r in self._lent.items()
+                              if r() is not None}
+        reused = buf is not None
+        if buf is None:
+            cap = -(-max(n, 1) // _ROW_QUANTUM) * _ROW_QUANTUM
+            buf = np.empty((cap, width), dtype)
+        with self._buf_lock:
+            self._lent[id(buf)] = weakref.ref(buf)
+        return buf, reused
+
+    def release(self, arrays) -> None:
+        """Hand back the buffers behind rows a gather returned, for later
+        gathers to refill.  Call it only once nothing reads those rows
+        any more.  Arrays that no lent buffer backs, or whose buffer was
+        handed back already, are ignored."""
+        with self._buf_lock:
+            for a in arrays:
+                if not isinstance(a, np.ndarray):
+                    continue
+                buf = a if a.base is None else a.base
+                ref = self._lent.pop(id(buf), None)
+                if ref is not None and ref() is buf:
+                    self._free.append(buf)
 
     def __del__(self):  # pragma: no cover - GC timing dependent
         try:
@@ -315,25 +386,47 @@ class FeatureLoader:
         chunks = [c for c in np.split(sorted_rows, cuts) if c.shape[0]]
         return chunks, order
 
-    def _gather(self, rows: np.ndarray) -> np.ndarray:
-        """Every host row gather: the span ``hyscale.load.gather``."""
-        with span("hyscale.load.gather"):
-            if self.num_threads == 1 or rows.shape[0] < 2 * self.num_threads:
-                return self.source.take(rows)
-            # chunked gather: with >1 OS threads numpy gathers overlap faults
+    def _gather(self, rows: np.ndarray) -> Tuple[np.ndarray, bool]:
+        """Every host row gather, into a lent buffer (``release`` hands it
+        back), and whether that buffer was a released one: the span
+        ``hyscale.load.gather`` (``reused=0|1``), on the calling thread
+        only.  A gather that raises keeps its buffer: a chunk still being
+        filled must not land in a buffer lent out again."""
+        n = int(rows.shape[0])
+        buf, reused = self._acquire(n)
+        out = buf[:n]
+        with span("hyscale.load.gather", reused=int(reused)):
+            if self.num_threads == 1 or n < 2 * self.num_threads:
+                self.source.take(rows, out=out)
+                return out, reused
+            # chunked gather: each pool thread fills its chunk's rows of
+            # `out` (in place where the chunk is a contiguous run of the
+            # request; partition-aligned chunks scatter back)
             chunks, order = self._split_chunks(rows)
-            parts = list(self._get_pool().map(self.source.take, chunks))
-            gathered = np.concatenate(parts, axis=0)
-            if order is None:
-                return gathered
-            out = np.empty_like(gathered)
-            out[order] = gathered      # scatter back into request order
-            return out
+            ends = np.cumsum([c.shape[0] for c in chunks]).tolist()
+
+            def fill(job):
+                chunk, lo, hi = job
+                if order is None:
+                    self.source.take(chunk, out=out[lo:hi])
+                else:
+                    out[order[lo:hi]] = self.source.take(chunk)
+
+            jobs = zip(chunks, [0] + ends[:-1], ends)
+            list(self._get_pool().map(fill, jobs))
+            return out, reused
 
     def _cast(self, x: np.ndarray) -> np.ndarray:
         if self.transfer_dtype == "bfloat16":
-            return x.astype(_BF16)
+            y = x.astype(_BF16)
+            self.release([x])      # the copy holds the rows now
+            return y
         return x
+
+    @staticmethod
+    def _gathers(reused: bool) -> Dict[str, int]:
+        return {"reused_gathers": int(reused),
+                "fresh_gathers": int(not reused)}
 
     def _frontier(self, batch: MiniBatch) -> np.ndarray:
         return np.asarray(batch.frontier(len(batch.fanouts)))
@@ -349,14 +442,15 @@ class FeatureLoader:
         t0 = time.perf_counter()
         stall0 = self._source_stall()
         frontier = self._frontier(batch)
-        x = self._cast(self._gather(frontier))
+        x, reused = self._gather(frontier)
+        x = self._cast(x)
         dt = time.perf_counter() - t0
         dest = "stats" if to_device else "host_stats"
         self._account(dest, LoadStats(rows=x.shape[0], bytes=x.nbytes,
                                       seconds=dt, total_rows=x.shape[0],
                                       unique_rows=x.shape[0],
                                       stall_seconds=self._source_stall()
-                                      - stall0))
+                                      - stall0, **self._gathers(reused)))
         return x
 
     def note_transfer_padding(self, rows: int, nbytes: int) -> None:
@@ -473,7 +567,8 @@ class FeatureLoader:
                 self._match_recent(recent_key, look)
         else:
             fresh_ids, recent_src, new_miss_index = look.miss_ids, [], None
-        rows = self._cast(self._gather(fresh_ids))
+        rows, reused = self._gather(fresh_ids)
+        rows = self._cast(rows)
         dt = time.perf_counter() - t0
         # deferred accounting commits only after the gather succeeded,
         # and against the ORIGINAL classification — the recent-LRU split
@@ -489,7 +584,8 @@ class FeatureLoader:
             dedup_saved_bytes=look.dup_miss_rows * row_bytes,
             recent_rows=n_recent,
             recent_saved_bytes=n_recent * row_bytes,
-            stall_seconds=self._source_stall() - stall0))
+            stall_seconds=self._source_stall() - stall0,
+            **self._gathers(reused)))
         shipped = None
         if use_recent:
             # rewrite the lookup onto the combined source layout and
@@ -543,7 +639,8 @@ class FeatureLoader:
             union_ids = np.unique(np.concatenate(fresh_sets))
         else:
             union_ids = np.zeros(0, dtype=np.int64)
-        rows = self._cast(self._gather(union_ids))
+        rows, reused = self._gather(union_ids)
+        rows = self._cast(rows)
         dt = time.perf_counter() - t0
         cache.record_union(union)
         row_bytes = cache.row_bytes
@@ -566,6 +663,7 @@ class FeatureLoader:
             tot_fresh += look.num_miss
             dup_pos += (look.miss_positions - sl.peer_positions
                         - look.num_miss)
+        self.release([rows])       # every trainer holds a copy of its slice
         multicast_extra = tot_fresh - int(union_ids.shape[0])
         self._account("stats", LoadStats(
             rows=int(union_ids.shape[0]), bytes=rows.nbytes, seconds=dt,
@@ -577,7 +675,8 @@ class FeatureLoader:
             peer_saved_bytes=tot_peer_pos * row_bytes,
             union_saved_bytes=multicast_extra * row_bytes,
             ici_bytes=(tot_peer_rows + multicast_extra) * row_bytes,
-            stall_seconds=self._source_stall() - stall0))
+            stall_seconds=self._source_stall() - stall0,
+            **self._gathers(reused)))
         return out
 
     def load_misses(self, batch: MiniBatch) -> MissBlock:

@@ -123,8 +123,10 @@ class FeatureSource(Protocol):
 
     ``take`` must return a fresh ``[len(rows), feat_dim]`` array in
     ``dtype`` for any int array of node ids (duplicates and arbitrary
-    order allowed).  Implementations are host-resident; device code only
-    ever sees the gathered result.
+    order allowed); given ``out`` (a C-contiguous array of that shape and
+    dtype) it writes the same rows into ``out`` and returns it.
+    Implementations are host-resident; device code only ever sees the
+    gathered result.
     """
 
     shape: Tuple[int, int]
@@ -132,7 +134,8 @@ class FeatureSource(Protocol):
     @property
     def dtype(self) -> np.dtype: ...
 
-    def take(self, rows: np.ndarray) -> np.ndarray: ...
+    def take(self, rows: np.ndarray,
+             out: Optional[np.ndarray] = None) -> np.ndarray: ...
 
 
 class DenseFeatures:
@@ -152,8 +155,20 @@ class DenseFeatures:
     def nbytes(self) -> int:
         return self.array.nbytes
 
-    def take(self, rows: np.ndarray) -> np.ndarray:
-        return np.take(self.array, np.asarray(rows, dtype=np.int64), axis=0)
+    def take(self, rows: np.ndarray,
+             out: Optional[np.ndarray] = None) -> np.ndarray:
+        rows = np.asarray(rows, dtype=np.int64)
+        # numpy copies ``out`` through a temporary under the default
+        # mode="raise"; "wrap" writes it in place and, for ids in [-N, N),
+        # reads the very rows "raise" reads: check that range first
+        n = self.shape[0]
+        if rows.shape[0] and (rows.min() < -n or rows.max() >= n):
+            bad = rows[(rows < -n) | (rows >= n)][0]
+            raise IndexError(
+                f"index {bad} is out of bounds for axis 0 with size {n}")
+        if out is None:
+            out = np.empty((rows.shape[0], self.shape[1]), self.array.dtype)
+        return np.take(self.array, rows, axis=0, out=out, mode="wrap")
 
     def __getitem__(self, rows):
         return self.take(np.atleast_1d(rows))
@@ -201,11 +216,13 @@ class PartitionedFeatures:
     def nbytes(self) -> int:
         return sum(p.nbytes for p in self.parts)
 
-    def take(self, rows: np.ndarray) -> np.ndarray:
+    def take(self, rows: np.ndarray,
+             out: Optional[np.ndarray] = None) -> np.ndarray:
         rows = np.asarray(rows, dtype=np.int64)
         part_id = rows // self.partition_rows
         offset = rows - part_id * self.partition_rows
-        out = np.empty((rows.shape[0], self.shape[1]), dtype=self.dtype)
+        if out is None:
+            out = np.empty((rows.shape[0], self.shape[1]), dtype=self.dtype)
         for pid in np.unique(part_id):
             sel = part_id == pid
             out[sel] = np.take(self.parts[pid], offset[sel], axis=0)
@@ -773,9 +790,12 @@ class MmapFeatures:
             total_new += new
         return total_new
 
-    def take(self, rows: np.ndarray) -> np.ndarray:
+    def take(self, rows: np.ndarray,
+             out: Optional[np.ndarray] = None) -> np.ndarray:
         rows = np.asarray(rows, dtype=np.int64)
-        out = np.empty((rows.shape[0], self.shape[1]), dtype=self._dtype)
+        if out is None:
+            out = np.empty((rows.shape[0], self.shape[1]),
+                           dtype=self._dtype)
         if rows.shape[0] == 0:
             return out
         part_id, offset = self._split_parts(rows)
@@ -889,8 +909,12 @@ class HashedFeatures:
     def nbytes_virtual(self) -> int:
         return self.shape[0] * self.shape[1] * self.dtype.itemsize
 
-    def take(self, rows: np.ndarray) -> np.ndarray:
+    def take(self, rows: np.ndarray,
+             out: Optional[np.ndarray] = None) -> np.ndarray:
         """Gather feature rows (vectorized splitmix-style hash -> [-1, 1])."""
+        if out is not None:
+            out[...] = self.take(rows)
+            return out
         rows = np.asarray(rows, dtype=np.uint64)
         x = (rows[:, None] * np.uint64(0x9E3779B97F4A7C15)
              + self._cols[None, :] * np.uint64(0xBF58476D1CE4E5B9)

@@ -292,10 +292,10 @@ def test_persistent_gather_pool_reused(small_ds):
     ds, _ = small_ds
     loader = FeatureLoader(ds, num_threads=4)
     rows = np.arange(0, ds.num_nodes, 2, dtype=np.int64)
-    a = loader._gather(rows)
+    a, _ = loader._gather(rows)
     pool = loader._pool
     assert pool is not None
-    b = loader._gather(rows)
+    b, _ = loader._gather(rows)
     assert loader._pool is pool          # reused, not rebuilt per call
     assert np.array_equal(a, b)
     assert np.array_equal(a, ds.take_features(rows))

@@ -1,6 +1,10 @@
 """Data-layer coverage: FeatureSource backend parity, the device-resident
 hot-feature cache, the cache + miss combine, and end-to-end loss
 equivalence of cached vs uncached training."""
+import gc
+import os
+import time
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -237,3 +241,190 @@ def test_cached_training_with_cpu_trainer_and_drm():
         cpu_b, accel_b = m.assignment
         assert cpu_b + accel_b * cfg.n_accel == cfg.total_batch
     assert tr.loader.stats.saved_bytes > 0
+
+
+# ------------------------------------------------ the loader's gather buffers
+
+
+@pytest.fixture(scope="module",
+                params=["dense", "hashed", "partitioned", "mmap"])
+def gather_ds(request, tmp_path_factory):
+    return make_dataset("ogbn-products", scale=0.001, seed=0,
+                        feature_backend=request.param, partition_rows=500,
+                        spill_dir=str(tmp_path_factory.mktemp("spill")))
+
+
+def _gather_ids(kind, n, size, rng):
+    if kind == "shuffled":
+        return rng.permutation(n)[:size]
+    if kind == "duplicated":
+        return rng.integers(0, 40, size)
+    if kind == "negative":                      # -N .. -1
+        return -rng.integers(1, n + 1, size)
+    return np.zeros(0, np.int64)
+
+
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("kind",
+                         ["shuffled", "duplicated", "negative", "empty"])
+def test_gather_buffers_equal_source_take(gather_ds, kind, threads):
+    """Rows gathered into the loader's buffers equal the source's own
+    ``take`` bit for bit, below and above the chunked split and across
+    the buffers' 1024-row capacity steps; a source that refuses ids
+    (the mmap tier refuses negative ones) refuses them in the loader
+    too.  Each released buffer is poisoned before the next size reuses
+    it, so a row left unfilled shows."""
+    src = gather_ds.feature_source
+    n = src.shape[0]
+    loader = FeatureLoader(gather_ds, num_threads=threads)
+    rng = np.random.default_rng(0)
+    try:
+        for size in (1, 5, 1024, 1025, 2049, 700):
+            ids = _gather_ids(kind, n, size, rng)
+            try:
+                want = src.take(ids)
+            except IndexError:
+                with pytest.raises(IndexError):
+                    loader._gather(ids)
+                continue
+            got, _ = loader._gather(ids)
+            assert got.shape == want.shape and got.dtype == want.dtype
+            assert got.tobytes() == want.tobytes()
+            loader.release([got])
+            got.base.fill(np.nan)
+    finally:
+        loader.close()
+
+
+@pytest.mark.parametrize("threads", [1, 3])
+def test_gather_buffers_raise_where_source_take_raises(gather_ds, threads):
+    src = gather_ds.feature_source
+    n = src.shape[0]
+    loader = FeatureLoader(gather_ds, num_threads=threads)
+    refused = []
+    try:
+        for ids in ([0, n], [n + 5] * 9 + [1], [-n - 1, 0, 3]):
+            ids = np.asarray(ids, np.int64)
+            try:
+                want = src.take(ids)
+            except IndexError:
+                refused.append(True)
+                with pytest.raises(IndexError):
+                    loader._gather(ids)
+                continue
+            refused.append(False)
+            assert loader._gather(ids)[0].tobytes() == want.tobytes()
+    finally:
+        loader.close()
+    # every stored tier refuses an id past the end; the dense array also
+    # one below -N (hashed rows exist for any id)
+    backend = type(src).__name__
+    if backend != "HashedFeatures":
+        assert refused[:2] == [True, True]
+    if backend == "DenseFeatures":
+        assert refused[2]
+
+
+def test_gather_buffer_reuse_and_release_rules():
+    ds = make_dataset("ogbn-products", scale=0.001, seed=0)
+    loader = FeatureLoader(ds, num_threads=2)
+    ids = np.arange(1500, dtype=np.int64)
+    a, reused_a = loader._gather(ids)
+    b, reused_b = loader._gather(ids[:900])
+    assert (reused_a, reused_b) == (False, False)
+    assert a.base.shape[0] == 2048 and b.base.shape[0] == 1024
+    loader.release([a, a, b[:10], np.zeros(3)])   # repeats and strangers
+    # the smallest released buffer that holds the rows
+    c, reused_c = loader._gather(ids[:1000])
+    assert reused_c and c.base is b.base
+    d, reused_d = loader._gather(ids[:1200])
+    assert reused_d and d.base is a.base
+    # none holds 3000 rows: a new one, and nothing is kept for it
+    loader.release([c])
+    e, reused_e = loader._gather(np.arange(3000, dtype=np.int64) % 1500)
+    assert not reused_e and e.base.shape[0] == 3072
+    assert loader._free == []
+    assert np.array_equal(e, ds.take_features(np.arange(3000) % 1500))
+    loader.close()
+    loader.release([d, e])           # after close: never taken back
+    assert loader._free == []
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_gather_counters_fresh_then_reused(depth):
+    """The first batch's gathers allocate and later ones mostly refill
+    released buffers (a request larger than every released buffer still
+    allocates), and the loader never keeps more buffers than the pipeline
+    holds batches at once (2 x depth + 3 at depth > 0: two queues, the
+    load and transfer stages, the train loop), two per batch here (the
+    CPU trainer's frontier, the TPU trainer's unique rows)."""
+    ds = make_dataset("ogbn-products", scale=0.002, seed=0)
+    g = GNNConfig(model="sage", layer_dims=(100, 32, 47), fanouts=(4, 3),
+                  num_classes=47)
+    tr = HybridGNNTrainer(ds, g, HybridConfig(
+        total_batch=128, n_accel=1, hybrid=True, use_drm=False,
+        tfp_depth=depth, cache_fraction=0.0, seed=0))
+
+    def counts():
+        s, h = tr.loader.stats, tr.loader.host_stats
+        return (s.fresh_gathers + h.fresh_gathers,
+                s.reused_gathers + h.reused_gathers)
+
+    assert tr.runtime.quantized_shares()[0] > 0     # the CPU trainer too
+    tr.train(1)
+    assert counts() == (2, 0)
+    tr.train(12)
+    before = counts()
+    tr.train(12)
+    fresh, reused = (a - b for a, b in zip(counts(), before))
+    assert fresh + reused == 2 * 12 and reused > fresh
+    assert tr.loader.host_stats.reused_gathers > 0
+    assert tr.loader.stats.reused_gathers > 0
+    gc.collect()
+    with tr.loader._buf_lock:
+        kept = len(tr.loader._free) + sum(
+            r() is not None for r in tr.loader._lent.values())
+    assert kept <= 2 * (2 * depth + 3 if depth else 1)
+    tr.close()
+
+
+def test_gather_buffers_never_lent_twice_under_contention():
+    """Gathers and releases from more threads than cores, with the
+    interpreter switching threads as often as it can: no buffer is held
+    by two gathers at once, and every gather holds its own rows."""
+    import sys
+    import threading
+    ds = make_dataset("ogbn-products", scale=0.001, seed=0)
+    loader = FeatureLoader(ds, num_threads=2)
+    held, errors, guard = set(), [], threading.Lock()
+    deadline = time.perf_counter() + 5.0
+
+    def worker(k):
+        rng = np.random.default_rng(k)
+        while time.perf_counter() < deadline and not errors:
+            ids = rng.integers(0, ds.num_nodes, int(rng.integers(1, 3000)))
+            x, _ = loader._gather(ids)
+            with guard:
+                if id(x.base) in held:
+                    errors.append("a buffer lent twice")
+                held.add(id(x.base))
+            if not np.array_equal(x, ds.take_features(ids)):
+                errors.append("rows of another gather")
+            with guard:
+                held.discard(id(x.base))
+            loader.release([x])
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(2 * (os.cpu_count() or 4))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(switch)
+        loader.close()
+    assert not errors, errors[:3]

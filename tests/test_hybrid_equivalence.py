@@ -8,14 +8,17 @@ mini-batch.  We verify the gradient identity exactly:
 which holds because each trainer's loss is a mean over its share.
 """
 import dataclasses
+import gc
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.core import Synchronizer
-from repro.graph import (GNNConfig, MiniBatch, NumpySampler, init_params,
-                         loss_fn, make_dataset)
+from repro.core import HybridConfig, HybridGNNTrainer, Synchronizer
+from repro.graph import (FeatureLoader, GNNConfig, MiniBatch, NumpySampler,
+                         init_params, loss_fn, make_dataset)
 
 
 def _concat_minibatches(a: MiniBatch, b: MiniBatch) -> MiniBatch:
@@ -119,3 +122,162 @@ def test_synchronizer_zero_weight_failed_trainer():
     sync.submit(1, {"w": jnp.full((2,), 99.0)}, 0.0)
     avg = sync.all_reduce()
     np.testing.assert_allclose(np.asarray(avg["w"]), np.ones(2))
+
+
+# ------------------------------------------ lifetime of the gather buffers
+
+
+@pytest.fixture
+def poisoned_release(monkeypatch):
+    """Every buffer the loader takes back is filled with NaN at once: a
+    batch that still read one, or a refill that left a row stale, would
+    carry NaN into the losses and params."""
+    release = FeatureLoader.release
+
+    def poisoning(self, arrays):
+        release(self, arrays)
+        with self._buf_lock:
+            for buf in self._free:
+                buf.fill(np.nan)
+
+    monkeypatch.setattr(FeatureLoader, "release", poisoning)
+
+
+def _pipelined_trainer(cache_fraction):
+    ds = make_dataset("ogbn-products", scale=0.002, seed=0)
+    g = GNNConfig(model="sage", layer_dims=(100, 32, 47), fanouts=(4, 3),
+                  num_classes=47)
+    return HybridGNNTrainer(ds, g, HybridConfig(
+        total_batch=128, n_accel=1, hybrid=True, use_drm=False,
+        tfp_depth=2, cache_fraction=cache_fraction, seed=0))
+
+
+def _losses_and_params(tr, iters=16):
+    losses = [m.loss for m in tr.train(iters)]
+    params = jax.tree.map(np.asarray, tr.params)
+    tr.close()
+    return losses, params
+
+
+@pytest.mark.parametrize("cache_fraction", [0.0, 0.2])
+def test_reused_gather_buffers_train_as_fresh_ones(cache_fraction,
+                                                   poisoned_release,
+                                                   monkeypatch):
+    """The pipelined trainer (CPU trainer beside one accelerator, two
+    batches ahead) trains bit for bit as it does when every gather
+    allocates its own buffer, while each buffer it hands back is
+    poisoned."""
+    with monkeypatch.context() as m:
+        m.setattr(FeatureLoader, "release", lambda self, arrays: None)
+        ref = _pipelined_trainer(cache_fraction)
+        want = _losses_and_params(ref)
+    assert ref.loader.host_stats.reused_gathers == 0
+    tr = _pipelined_trainer(cache_fraction)
+    got = _losses_and_params(tr)
+    # the pipeline holds up to 7 batches: the later 9 refill buffers
+    assert (tr.loader.host_stats.reused_gathers
+            + tr.loader.stats.reused_gathers) > 0
+    assert all(np.isfinite(got[0]))
+    assert got[0] == want[0]
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        assert np.array_equal(a, b)
+
+
+def _within(fn, timeout=300):
+    """``fn()``'s result and error, from a thread that must end in time."""
+    out, errors = [], []
+
+    def run():
+        try:
+            out.append(fn())
+        except RuntimeError as e:
+            errors.append(str(e))
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(timeout)
+    assert not t.is_alive()
+    return out, errors
+
+
+def test_gather_buffers_through_failures(poisoned_release):
+    """A trainer that dies, a pipeline stage that fails and a trainer
+    step that raises neither hang the trainer nor hand a buffer out
+    twice: batches that never finished keep theirs (a later gather
+    allocates anew), and the loader keeps no more buffers than the
+    pipeline holds at once (7 batches at depth 2, two gathers each)."""
+    tr = _pipelined_trainer(0.0)
+    most_held = 2 * (2 * 2 + 3)
+    tr.inject_failure("cpu", at_iteration=3)
+    (hist,), errors = _within(lambda: tr.train(8))
+    assert not errors and all(np.isfinite(m.loss) for m in hist)
+    assert "cpu" in tr._failed
+
+    transfer = tr._stage_transfer
+
+    def failing_transfer(item):
+        if item.payload["iteration"] == 2:
+            raise RuntimeError("transfer failed")
+        return transfer(item)
+
+    tr._stage_transfer = failing_transfer
+    assert _within(lambda: tr.train(6))[1] == ["transfer failed"]
+    del tr._stage_transfer
+
+    grad = tr._grad_jit
+
+    def failing_step(*args):
+        raise RuntimeError("device step failed")
+
+    tr._grad_jit = failing_step
+    assert _within(lambda: tr.train(4))[1] == ["device step failed"]
+    tr._grad_jit = grad
+
+    (hist,), errors = _within(lambda: tr.train(8))
+    assert not errors and all(np.isfinite(m.loss) for m in hist)
+    gc.collect()
+    with tr.loader._buf_lock:
+        lent = sum(r() is not None for r in tr.loader._lent.values())
+        assert len(tr.loader._free) + lent <= most_held
+    tr.close()
+    assert tr.loader._free == [] and tr.loader._lent == {}
+
+
+def test_rows_a_device_array_reads_in_place_are_never_refilled():
+    """XLA's CPU client keeps a 64-byte-aligned host array instead of
+    copying it: the CPU trainer's input then reads the loader's buffer,
+    and a caller that keeps that input past its batch (as the benchmark
+    keeps the first steps' inputs) must find it unchanged."""
+    from repro.core.hybrid import _reads_in_place
+    cpu = jax.devices("cpu")[0]
+    base = np.zeros(1000 * 16 + 64, np.float32)
+    lo = (-base.ctypes.data % 64) // 4
+    aligned = base[lo:lo + 16000].reshape(1000, 16)
+    assert _reads_in_place(jax.device_put(aligned, cpu), aligned)
+    shifted = base[lo + 4:lo + 4 + 16000].reshape(1000, 16)
+    assert not _reads_in_place(jax.device_put(shifted, cpu), shifted)
+
+    ds = make_dataset("ogbn-products", scale=0.002, seed=0,
+                      feature_backend="hashed")
+    ds.features = ds.features.take(np.arange(ds.num_nodes))[:, :16].copy()
+    ds.feat_dim = 16
+    g = GNNConfig(model="sage", layer_dims=(16, 32, 47), fanouts=(4, 3),
+                  num_classes=47)
+    tr = HybridGNNTrainer(ds, g, HybridConfig(
+        total_batch=64, n_accel=1, hybrid=True, use_drm=False,
+        tfp_depth=2, cache_fraction=0.0, seed=0))
+    kept = []
+    run = tr._run_trainers
+
+    def keeping(item):
+        p = item.payload
+        kept.append((np.asarray(p["minibatch"]["cpu"].frontier(2)),
+                     np.asarray(p["features"]["cpu"])))
+        return run(item)
+
+    tr._run_trainers = keeping
+    tr.train(40)
+    tr.close()
+    assert tr.loader.host_stats.reused_gathers > 0
+    for frontier, x0 in kept:
+        assert np.array_equal(x0, ds.take_features(frontier))

@@ -381,7 +381,7 @@ def test_loader_partition_aligned_chunks_disjoint(tmp_path):
             assert not (touched[i] & touched[j]), "windows overlap"
     assert sum(c.shape[0] for c in chunks) == rows.shape[0]
     # and the gather stays byte-identical to the single-thread path
-    assert np.array_equal(loader._gather(rows), ds.take_features(rows))
+    assert np.array_equal(loader._gather(rows)[0], ds.take_features(rows))
     loader.close()
 
 
@@ -393,7 +393,7 @@ def test_loader_unpartitioned_split_unchanged():
     chunks, order = loader._split_chunks(rows)
     assert order is None          # legacy order-preserving array_split
     assert np.array_equal(np.concatenate(chunks), rows)
-    assert np.array_equal(loader._gather(rows), ds.take_features(rows))
+    assert np.array_equal(loader._gather(rows)[0], ds.take_features(rows))
     loader.close()
 
 

@@ -138,6 +138,27 @@ def test_child_spans_lie_inside_their_parent(traced):
             assert any(_inside(e, p) for p in parents), (part, e)
 
 
+def test_gather_span_once_per_gather_on_the_load_thread(traced):
+    """``hyscale.load.gather`` opens once per loader gather (one for each
+    trainer of the batch) on the load stage's thread, never in the
+    gather's pool threads, and says whether its buffer was a reused one."""
+    _, _, events = traced
+    gathers = _of(events, "hyscale.load.gather")
+    per_load = []
+    for i in range(ITERS):
+        (load,) = _of(events, "hyscale.load", i)
+        inside = [e for e in gathers if _inside(e, load)]
+        trainers = sum(len(_of(events, name, i)) for name in TRAINERS)
+        assert len(inside) == trainers == 2, (i, inside)
+        per_load += inside
+    assert len(per_load) == len(gathers)
+    assert {e[0] for e in gathers} == {e[0] for e in
+                                       _of(events, "hyscale.load")}
+    assert all(e[4]["reused"] in (0, 1) for e in gathers)
+    # the warm-up's released buffers serve the window
+    assert any(e[4]["reused"] == 1 for e in gathers)
+
+
 def test_stage_times_are_their_spans(traced):
     """Each time the program keeps is its span's duration: within 1% in
     the median iteration.  The two clocks are read one Python call apart,
